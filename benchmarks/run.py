@@ -10,7 +10,7 @@ import sys
 import traceback
 
 
-def main() -> None:
+def main() -> int:
     def emit(name, us, derived=""):
         print(f"{name},{us:.3f},{derived}")
 
@@ -20,13 +20,17 @@ def main() -> None:
             ("etcd_throughput", throughput), ("elasticity", elasticity),
             ("kernels", kernelbench), ("roofline", roofline_table),
             ("serving", serving), ("comm", comm)]
+    failed = []
     for name, mod in mods:
         try:
             mod.main(emit)
         except Exception as e:
+            # keep going so every module reports, but never exit 0
             traceback.print_exc()
             print(f"{name},0,ERROR {e}")
+            failed.append(name)
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

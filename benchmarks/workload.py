@@ -44,7 +44,7 @@ def bench(seed: int = 0):
             # strong scaling: fixed problem, so per-node work ~ 1/size
             job = mc.instance.submit(
                 JobSpec(n_nodes=size, walltime=0,
-                        command="lammps-proxy"))
+                        command="lammps-proxy-smoke"))
             t_submit = clock.now
             clock.run(stop_when=lambda: job.result is not None)
             # paper Fig 5: "time for the launcher to submit and
@@ -67,7 +67,7 @@ def bench(seed: int = 0):
             res = {}
             t0 = clock2.now
             mj.mpirun(JobSpec(n_nodes=size, walltime=0,
-                              command="lammps-proxy"),
+                              command="lammps-proxy-smoke"),
                       lambda wall: res.setdefault("wall", wall))
             clock2.run(stop_when=lambda: "wall" in res)
             mpi_launch.append(net2.ssh_handshake * size + res["wall"])

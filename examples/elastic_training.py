@@ -21,7 +21,7 @@ from repro.train import Trainer
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=200)
-    ap.add_argument("--arch", default="granite-moe-1b-a400m")
+    ap.add_argument("--arch", default="granite-moe-1b-a400m-smoke")
     args = ap.parse_args()
 
     # --- control plane: the operator schedules the training job ---
@@ -43,7 +43,7 @@ def main():
           f"{list(job.allocation.hosts)}")
 
     # --- data plane: the allocated job runs the Trainer ---
-    cfg = registry.smoke(args.arch)
+    cfg = registry.get(args.arch)
     tcfg = TrainConfig(learning_rate=1e-3, total_steps=args.steps,
                        warmup_steps=10)
     shape = WorkloadShape("t", "train", 64, 8)

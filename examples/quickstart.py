@@ -30,11 +30,12 @@ def main():
     print(f"MiniCluster ready in {t_ready:.1f}s "
           f"({mc.pool.n_up()} brokers up)")
 
-    # apply real JAX training workloads (reduced configs run on this
-    # host, on the sub-mesh each job's allocation describes)
+    # apply real JAX training workloads (the reduced "-smoke" configs
+    # run on this host, on the sub-mesh each job's allocation describes)
     handles = []
-    for arch, nodes in [("yi-6b", 4), ("granite-moe-1b-a400m", 2),
-                        ("lammps-proxy", 2)]:
+    for arch, nodes in [("yi-6b-smoke", 4),
+                        ("granite-moe-1b-a400m-smoke", 2),
+                        ("lammps-proxy-smoke", 2)]:
         h = mc.apply(WorkloadSpec(
             kind="train", arch=arch, name=f"qs-{arch}", user="quickstart",
             resources=ResourceSpec(n_nodes=nodes),

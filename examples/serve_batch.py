@@ -4,7 +4,7 @@ the reconciler binds an elastic serving engine to the job's allocation,
 and requests stream through the handle — half of them submitted
 mid-decode (the continuous-batching path: no restart, no recompile).
 
-    PYTHONPATH=src python examples/serve_batch.py [--arch yi-6b]
+    PYTHONPATH=src python examples/serve_batch.py [--arch yi-6b-smoke]
 """
 import argparse
 import time
@@ -20,7 +20,7 @@ from repro.spec import ResourceSpec, ServeSpec, WorkloadSpec
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="yi-6b")
+    ap.add_argument("--arch", default="yi-6b-smoke")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=24)
     ap.add_argument("--gen", type=int, default=12)
@@ -49,7 +49,7 @@ def main():
     ex, job = h.executor, h.job
 
     rng = np.random.default_rng(0)
-    vocab = registry.smoke(args.arch).vocab_size
+    vocab = registry.get(args.arch).vocab_size
     # the spec's n_requests arrive at placement; stagger the rest in
     # mid-decode through the handle (continuous batching)
     clock.run(until=clock.now + 5_000,

@@ -38,10 +38,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-try:                                    # jax >= 0.5 moved it out of
-    from jax import shard_map as _shard_map      # experimental
-except ImportError:                     # pragma: no cover - version compat
-    from jax.experimental.shard_map import shard_map as _shard_map
+from jax import shard_map as _shard_map
 
 from repro.comm import compress as efc
 from repro.comm.topology import CommTopology
@@ -242,7 +239,7 @@ def sync_grads(stacked, defs, mesh, policy: CommPolicy,
             return jax.tree_util.tree_map(
                 lambda g: _sync_leaf(g, None)[0], gs)
         synced = _shard_map(body, mesh=mesh, in_specs=(in_g,),
-                            out_specs=out_g, check_rep=False)(stacked)
+                            out_specs=out_g, check_vma=False)(stacked)
         return synced, residual
 
     in_e = ef_specs(defs, mesh, strategy)
@@ -256,7 +253,7 @@ def sync_grads(stacked, defs, mesh, policy: CommPolicy,
 
     synced, new_ef = _shard_map(
         body, mesh=mesh, in_specs=(in_g, in_e), out_specs=(out_g, in_e),
-        check_rep=False)(stacked, residual)
+        check_vma=False)(stacked, residual)
     return synced, new_ef
 
 

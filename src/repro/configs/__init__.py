@@ -3,4 +3,4 @@ from repro.configs.base import (  # noqa: F401
     ShardingStrategy, TrainConfig, WorkloadShape, XLSTMConfig, replace,
     shape_applicable,
 )
-from repro.configs.registry import ARCH_IDS, EXTRA_IDS, all_configs, get, smoke  # noqa: F401
+from repro.configs.registry import ARCH_IDS, CONFIG_IDS, EXTRA_IDS, all_configs, get  # noqa: F401

@@ -1,7 +1,7 @@
 """Executors: how scheduled jobs become actual work.
 
 ``JaxWorkloadExecutor`` runs REAL JAX compute — a jitted train step of
-the job's configured architecture (reduced config on this CPU host) —
+the config its job's command names in the registry —
 and converts measured wall time into simulated job walltime.  The
 PMI/bootstrap cost is modeled structurally: Flux bootstraps MPI ranks
 through its always-up brokers (flux-pmix; ~O(log N) TBON hops), while
@@ -16,18 +16,10 @@ import time
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional
 
+from repro.configs import registry
 from repro.core.jobspec import Job, JobSpec, JobState
 from repro.core.resource_graph import ResourceSet
 from repro.core.sim import NetModel, SimClock
-
-
-def smoke_config_for(command: str):
-    """Resolve a job command to a reduced arch config (shared by all
-    executors; unknown commands fall back to the paper's proxy app)."""
-    from repro.configs import registry
-    return registry.smoke(command if command in
-                          registry.ARCH_IDS + registry.EXTRA_IDS
-                          else "lammps-proxy")
 
 
 def tbon_bootstrap_cost(net: NetModel, n_nodes: int, fanout: int) -> float:
@@ -74,7 +66,7 @@ class JaxWorkloadExecutor:
         from repro.configs.base import WorkloadShape
         from repro.models import Model, example_batch
 
-        cfg = smoke_config_for(command)
+        cfg = registry.get(command)
         model = Model(cfg)
         params = model.init(jax.random.PRNGKey(0))
         batch = example_batch(cfg, WorkloadShape("bench", "train", 32, 2))
@@ -167,7 +159,7 @@ class SubmeshExecutor:
         from repro.dist import steps as dsteps
         from repro.models import example_batch
 
-        cfg = self.cfg or smoke_config_for(command)
+        cfg = self.cfg or registry.get(command)
         strategy = self.strategy or BASELINE
         tcfg = TrainConfig(total_steps=max(self.steps, 1), warmup_steps=0)
         # batch rows cover the data axis; at least 2 rows per shard
@@ -353,7 +345,7 @@ class ElasticTrainExecutor(SubmeshExecutor):
         from repro.ckpt import CheckpointManager
         from repro.configs import TrainConfig
         from repro.configs.base import WorkloadShape
-        cfg = self.cfg or smoke_config_for(job.spec.command)
+        cfg = self.cfg or registry.get(job.spec.command)
         tcfg = self.tcfg or TrainConfig(total_steps=self.total_steps,
                                         warmup_steps=0)
         shape = WorkloadShape("elastic", "train", self.seq_len,
@@ -584,7 +576,7 @@ class ServeExecutor:
         from repro.serve import Engine, EngineConfig
         ecfg = self.engine_config or EngineConfig(
             n_slots=4, page_size=8, max_seq_len=64, max_prompt_len=16)
-        eng = Engine(self.cfg or smoke_config_for(command), ecfg,
+        eng = Engine(self.cfg or registry.get(command), ecfg,
                      strategy=self.strategy or BASELINE, mesh=mesh)
         # compile outside timing (the executor contract shared with
         # JaxWorkloadExecutor/SubmeshExecutor): one warm request drives
@@ -628,6 +620,7 @@ class ServeExecutor:
             "hosts": list(rset.hosts),
             "n_requests": len(reqs),
             "n_tokens": n_tok,
+            "tokens": [list(r.tokens) for r in reqs],
             "tokens_per_s": n_tok / max(elapsed, 1e-9),
             "ttft_mean_s": sum(ttfts) / max(len(ttfts), 1),
             "measured_s": measured,
@@ -646,12 +639,12 @@ class FleetServeExecutor(ServeExecutor):
     nodes_per_replica`` hosts; this executor slices it pod-major into
     ``replicas`` consecutive host groups, raises a submesh per group,
     and builds one :class:`repro.serve.Router` over shape-identical
-    engines sharing one host-side parameter copy (so the fleet is a
-    true replica set and the shared prefix cache's token-identity
-    guarantee holds).  Dispatch, tenant fairness and the prefix cache
-    all live in the router; ``ran`` records per-replica meshes and the
-    fleet-level stats, plus the router's ``desired_replicas`` signal
-    for the autoscaler.
+    engines that make the same weights from one seed, each on its own
+    devices (so the fleet is a true replica set and the shared prefix
+    cache's token-identity guarantee holds).  Dispatch, tenant fairness
+    and the prefix cache all live in the router; ``ran`` records
+    per-replica meshes and the fleet-level stats, plus the router's
+    ``desired_replicas`` signal for the autoscaler.
     """
 
     def __init__(self, clock: SimClock, net: NetModel, replicas: int = 2,
@@ -685,19 +678,18 @@ class FleetServeExecutor(ServeExecutor):
         fleet = self._fleets.get(key)
         if fleet is not None:
             return fleet
-        import jax
         from repro.configs import BASELINE
         from repro.dist.sharding import submesh_for
-        from repro.models.model import Model
         from repro.serve import Engine, EngineConfig, Router
-        cfg = self.cfg or smoke_config_for(command)
+        cfg = self.cfg or registry.get(command)
         ecfg = self.engine_config or EngineConfig(
             n_slots=4, page_size=8, max_seq_len=64, max_prompt_len=16)
-        params = Model(cfg).init(jax.random.PRNGKey(0))
         engines = []
         for sub in self._slices(rset):
+            # one seed: every replica makes the same weights on its own
+            # devices
             eng = Engine(cfg, ecfg, strategy=self.strategy or BASELINE,
-                         mesh=submesh_for(sub), params=params, seed=0)
+                         mesh=submesh_for(sub), seed=0)
             # compile outside timing (the shared executor contract)
             warm = eng.submit(
                 [1] * min(self.prompt_len, ecfg.max_prompt_len),
@@ -743,6 +735,7 @@ class FleetServeExecutor(ServeExecutor):
             "hosts": list(rset.hosts),
             "n_requests": len(reqs),
             "n_tokens": n_tok,
+            "tokens": [list(r.tokens) for r in reqs],
             "tokens_per_s": n_tok / max(elapsed, 1e-9),
             "ttft_mean_s": sum(ttfts) / max(len(ttfts), 1),
             "n_prefills": stats["n_prefills"],
@@ -822,7 +815,6 @@ class ElasticServeExecutor(ServeExecutor):
         self.drain_ticks = drain_ticks
         self.mc = None
         self.sessions: Dict[int, _ServeSession] = {}
-        self._params: Dict[str, object] = {}     # cfg name -> init params
         self.phase_cb = None
         # optional obs.trace.Tracer: park/rebuild/adopt become spans on
         # the trace ``resize-<jobid>`` (sim axis, wall costs in attrs)
@@ -907,15 +899,6 @@ class ElasticServeExecutor(ServeExecutor):
                               in_flight=len(sch.running),
                               adopted_arrivals=n_arrivals)
 
-    def _host_params(self, cfg):
-        params = self._params.get(cfg.name)
-        if params is None:
-            import jax
-            from repro.models import Model
-            params = Model(cfg).init(jax.random.PRNGKey(self.seed))
-            self._params[cfg.name] = params
-        return params
-
     # -- request API --------------------------------------------------------
     def submit_request(self, job: Job, prompt, max_new: int = None,
                        temperature: float = 0.0):
@@ -942,7 +925,7 @@ class ElasticServeExecutor(ServeExecutor):
         if ses is not None:
             return ses
         from repro.serve import EngineConfig
-        cfg = self.cfg or smoke_config_for(job.spec.command)
+        cfg = self.cfg or registry.get(job.spec.command)
         ecfg = self.engine_config or EngineConfig(
             n_slots=4, page_size=8, max_seq_len=64, max_prompt_len=16)
         ses = _ServeSession(job=job, cfg=cfg, ecfg=ecfg)
@@ -960,9 +943,11 @@ class ElasticServeExecutor(ServeExecutor):
         gen = ses.generation
         mesh = submesh_for(rset)
         t0 = time.perf_counter()
+        # the seed alone fixes the weights: every rebuild makes the same
+        # ones on its new devices
         eng = Engine(ses.cfg, ses.ecfg,
                      strategy=self.strategy or BASELINE, mesh=mesh,
-                     params=self._host_params(ses.cfg), seed=self.seed)
+                     seed=self.seed)
         if ses.parked is not None:
             self._restore(ses, eng)
         else:
@@ -1150,7 +1135,10 @@ class _FleetSession:
     min_total: int = 0                # requests the job must serve
     ticks: int = 0                    # router ticks that did work
     generation: int = 0
-    params: object = None             # CURRENT host-side param tree
+    # CURRENT params: None until the first promotion (every engine
+    # makes the seeded weights on its own devices), then the promoted
+    # version's host-side bf16 tree
+    params: object = None
     version: int = 0                  # bumps on each completed promotion
     pending_replicas: Optional[int] = None   # scale target not yet met
     pending_source: str = ""
@@ -1216,7 +1204,6 @@ class ElasticFleetServeExecutor(ServeExecutor):
         self.sim_tick_time = sim_tick_time
         self.mc = None
         self.sessions: Dict[int, _FleetSession] = {}
-        self._params: Dict[str, object] = {}     # cfg name -> init params
         self.phase_cb = None
         # optional obs.trace.Tracer: scale/promotion become events on
         # the trace ``promo-<jobid>`` (sim axis, wall costs in attrs)
@@ -1253,15 +1240,6 @@ class ElasticFleetServeExecutor(ServeExecutor):
                                   target_replicas=want, source=source)
 
     # -- engine construction -------------------------------------------------
-    def _host_params(self, cfg):
-        params = self._params.get(cfg.name)
-        if params is None:
-            import jax
-            from repro.models import Model
-            params = Model(cfg).init(jax.random.PRNGKey(self.seed))
-            self._params[cfg.name] = params
-        return params
-
     def _slices(self, rset: ResourceSet,
                 replicas: int) -> List[ResourceSet]:
         """Pod-major consecutive host groups, one per replica."""
@@ -1326,7 +1304,7 @@ class ElasticFleetServeExecutor(ServeExecutor):
         if ses is not None:
             return ses
         from repro.serve import EngineConfig
-        cfg = self.cfg or smoke_config_for(job.spec.command)
+        cfg = self.cfg or registry.get(job.spec.command)
         ecfg = self.engine_config or EngineConfig(
             n_slots=4, page_size=8, max_seq_len=64, max_prompt_len=16)
         ses = _FleetSession(job=job, cfg=cfg, ecfg=ecfg)
@@ -1342,8 +1320,6 @@ class ElasticFleetServeExecutor(ServeExecutor):
         ses = self._session(job)
         ses.generation += 1
         gen = ses.generation
-        if ses.params is None:
-            ses.params = self._host_params(ses.cfg)
         if ses.promo is not None:
             # a requeue mid-promotion aborts the roll: the rebuilt fleet
             # serves the OLD params uniformly (promote again to retry)
@@ -1458,6 +1434,13 @@ class ElasticFleetServeExecutor(ServeExecutor):
         if ses.promo is not None:
             raise RuntimeError(
                 f"job {job.jobid}: promotion already in progress")
+        import jax
+        import jax.numpy as jnp
+        import numpy as np
+        # one host-side bf16 copy feeds every replica's rebuild
+        params = jax.tree_util.tree_map(
+            lambda x: np.asarray(jax.device_get(x)).astype(jnp.bfloat16),
+            params)
         n_rep = len(ses.router.engines) if ses.router is not None else 0
         in_flight = (sum(len(e.scheduler.running)
                          for e in ses.router.engines)

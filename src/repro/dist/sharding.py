@@ -44,15 +44,11 @@ DATA_AXES = ("pod", "data")
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str],
               devices=None) -> Mesh:
-    """Version-compatible mesh builder (``AxisType`` landed after 0.4.37)."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            tuple(shape), tuple(axes), devices=devices,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    if devices is None:
-        return jax.make_mesh(tuple(shape), tuple(axes))
-    arr = np.asarray(devices, dtype=object).reshape(tuple(shape))
-    return Mesh(arr, tuple(axes))
+    """A mesh whose axes are all ``Auto`` (sharding propagates through
+    ops, as the rule tables assume)."""
+    return jax.make_mesh(
+        tuple(shape), tuple(axes), devices=devices,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def axis_size(mesh: Mesh, axes: Tuple[str, ...]) -> int:

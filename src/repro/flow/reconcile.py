@@ -79,7 +79,8 @@ class PipelineReconciler:
                 so = stage_opts.get(s.name, {})
                 scfg = so.get("cfg", cfg)
                 if scfg is None:
-                    scfg = wr._registry_cfg(s.workload)
+                    from repro.configs import registry
+                    scfg = registry.get(s.workload.arch)
                 strat = so.get("strategy", strategy)
                 if strat is None:
                     strat = s.workload.resolved_strategy

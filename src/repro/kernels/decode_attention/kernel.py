@@ -122,7 +122,7 @@ def _paged_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(pi * page < length)            # skip unfilled pages
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)            # g x d
-        k = k_ref[0, :, 0].astype(jnp.float32)         # page x d
+        k = k_ref[0, 0].astype(jnp.float32)            # page x d
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         kpos = pi * page + jax.lax.broadcasted_iota(
@@ -133,7 +133,7 @@ def _paged_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
         l_scr[...] = l_scr[...] * alpha + p.sum(axis=1, keepdims=True)
-        v = v_ref[0, :, 0].astype(jnp.float32)
+        v = v_ref[0, 0].astype(jnp.float32)
         acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot(
             p.astype(v.dtype), v, preferred_element_type=jnp.float32)
         m_scr[...] = m_new
@@ -147,10 +147,11 @@ def _paged_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
 def paged_decode_attention_kernel(q, k_pages, v_pages, block_table,
                                   lengths, *, scale=None, interpret=False):
-    """q: (B, 1, H, D); k_pages, v_pages: (P, page, Hkv, D);
-    block_table: (B, pages_per_slot) int32; lengths: (B,) int32."""
+    """q: (B, 1, H, D); k_pages, v_pages: (P, Hkv, page, D) — one
+    page of one kv head is a contiguous (page, D) tile, the kernel's
+    block; block_table: (B, pages_per_slot) int32; lengths: (B,) int32."""
     b, one, h, d = q.shape
-    _, page, hkv, _ = k_pages.shape
+    _, hkv, page, _ = k_pages.shape
     maxp = block_table.shape[1]
     g = h // hkv
     scale = scale or d ** -0.5
@@ -162,10 +163,10 @@ def paged_decode_attention_kernel(q, k_pages, v_pages, block_table,
         in_specs=[
             pl.BlockSpec((1, 1, g, d),
                          lambda b_, hk, pi, bt, ln: (b_, hk, 0, 0)),
-            pl.BlockSpec((1, page, 1, d),
-                         lambda b_, hk, pi, bt, ln: (bt[b_, pi], 0, hk, 0)),
-            pl.BlockSpec((1, page, 1, d),
-                         lambda b_, hk, pi, bt, ln: (bt[b_, pi], 0, hk, 0)),
+            pl.BlockSpec((1, 1, page, d),
+                         lambda b_, hk, pi, bt, ln: (bt[b_, pi], hk, 0, 0)),
+            pl.BlockSpec((1, 1, page, d),
+                         lambda b_, hk, pi, bt, ln: (bt[b_, pi], hk, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, g, d),
                                lambda b_, hk, pi, bt, ln: (b_, hk, 0, 0)),
@@ -212,7 +213,7 @@ def _paged_prefill_kernel(bt_ref, start_ref, nv_ref, q_ref, k_ref, v_ref,
     @pl.when(pi * page < filled)            # skip pages past the fill
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)            # rows x d
-        k = k_ref[0, :, 0].astype(jnp.float32)         # page x d
+        k = k_ref[0, 0].astype(jnp.float32)            # page x d
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         kpos = pi * page + jax.lax.broadcasted_iota(
@@ -225,7 +226,7 @@ def _paged_prefill_kernel(bt_ref, start_ref, nv_ref, q_ref, k_ref, v_ref,
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
         l_scr[...] = l_scr[...] * alpha + p.sum(axis=1, keepdims=True)
-        v = v_ref[0, :, 0].astype(jnp.float32)
+        v = v_ref[0, 0].astype(jnp.float32)
         acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot(
             p.astype(v.dtype), v, preferred_element_type=jnp.float32)
         m_scr[...] = m_new
@@ -241,11 +242,11 @@ def paged_prefill_attention_kernel(q, k_pages, v_pages, block_table,
                                    start, n_valid, *, scale=None,
                                    interpret=False):
     """q: (B, C, H, D) chunk queries at positions start..start+C-1;
-    k_pages, v_pages: (P, page, Hkv, D) with the chunk's own KV already
+    k_pages, v_pages: (P, Hkv, page, D) with the chunk's own KV already
     written; block_table: (B, pages_per_slot) int32; start, n_valid:
     (B,) int32.  Rows past ``n_valid`` produce garbage (discarded)."""
     b, chunk, h, d = q.shape
-    _, page, hkv, _ = k_pages.shape
+    _, hkv, page, _ = k_pages.shape
     maxp = block_table.shape[1]
     g = h // hkv
     scale = scale or d ** -0.5
@@ -259,12 +260,12 @@ def paged_prefill_attention_kernel(q, k_pages, v_pages, block_table,
         in_specs=[
             pl.BlockSpec((1, 1, chunk * g, d),
                          lambda b_, hk, pi, bt, st, nv: (b_, hk, 0, 0)),
-            pl.BlockSpec((1, page, 1, d),
+            pl.BlockSpec((1, 1, page, d),
                          lambda b_, hk, pi, bt, st, nv:
-                         (bt[b_, pi], 0, hk, 0)),
-            pl.BlockSpec((1, page, 1, d),
+                         (bt[b_, pi], hk, 0, 0)),
+            pl.BlockSpec((1, 1, page, d),
                          lambda b_, hk, pi, bt, st, nv:
-                         (bt[b_, pi], 0, hk, 0)),
+                         (bt[b_, pi], hk, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, chunk * g, d),
                                lambda b_, hk, pi, bt, st, nv:

@@ -27,21 +27,27 @@ def decode_ref(q, k, v, cache_len, *, scale=None):
     return out.reshape(b, sq, h, d).astype(q.dtype)
 
 
+def gather_pages(pages, block_table):
+    """(P, Hkv, page, D) pool + (B, maxp) block table -> the slots'
+    contiguous (B, maxp * page, Hkv, D) caches."""
+    b, maxp = block_table.shape
+    _, hkv, page, d = pages.shape
+    return pages[block_table].transpose(0, 1, 3, 2, 4).reshape(
+        b, maxp * page, hkv, d)
+
+
 def paged_decode_ref(q, k_pages, v_pages, block_table, lengths, *,
                      scale=None):
     """Gather-then-attend oracle for the paged layout.
 
-    q: (B, 1, H, D); k_pages, v_pages: (P, page_size, Hkv, D);
+    q: (B, 1, H, D); k_pages, v_pages: (P, Hkv, page_size, D);
     block_table: (B, pages_per_slot) int32; lengths: (B,) valid tokens.
     The gather materializes each slot's pages as a contiguous
     (B, pages_per_slot * page_size) cache, so this is bit-identical to
     ``decode_ref`` over the equivalent contiguous layout.
     """
-    b = q.shape[0]
-    _, page, hkv, d = k_pages.shape
-    maxp = block_table.shape[1]
-    k = k_pages[block_table].reshape(b, maxp * page, hkv, d)
-    v = v_pages[block_table].reshape(b, maxp * page, hkv, d)
+    k = gather_pages(k_pages, block_table)
+    v = gather_pages(v_pages, block_table)
     return decode_ref(q, k, v, lengths, scale=scale)
 
 
@@ -64,12 +70,12 @@ def paged_prefill_ref(q, k_pages, v_pages, block_table, start, n_valid, *,
     """
     from repro import flags
     b, sq, h, d = q.shape
-    _, page, hkv, _ = k_pages.shape
+    _, hkv, page, _ = k_pages.shape
     maxp = block_table.shape[1]
     g = h // hkv
     scale = scale or d ** -0.5
-    k = k_pages[block_table].reshape(b, maxp * page, hkv, d)
-    v = v_pages[block_table].reshape(b, maxp * page, hkv, d)
+    k = gather_pages(k_pages, block_table)
+    v = gather_pages(v_pages, block_table)
     if g > 1:                      # mirror flash ref's GQA repeat
         k = jnp.repeat(k, g, axis=2)
         v = jnp.repeat(v, g, axis=2)

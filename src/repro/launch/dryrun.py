@@ -1,14 +1,19 @@
 import os
+# 512 placeholder devices on the CPU platform, pinned explicitly: on a
+# machine with a chip the default backend would be the TPU, which would
+# take the chip and could not build the production mesh
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 os.environ["REPRO_DRYRUN_INNER"] = "1"   # unroll inner streaming loops
 
 """Multi-pod dry-run: lower + compile every (arch x shape) cell on the
 production mesh and extract memory / cost / collective stats.
 
-The XLA_FLAGS line MUST precede any jax import: jax locks the device
-count at first init, and the dry-run needs 512 placeholder host devices
-to build the 16x16 and 2x16x16 production meshes.  Smoke tests and
-benchmarks do NOT set this (they see the host's real device count).
+The environment lines MUST precede any jax import: jax locks the
+platform and device count at first init, and the dry-run needs 512
+placeholder host devices to build the 16x16 and 2x16x16 production
+meshes.  Smoke tests and benchmarks do NOT set this (they see the
+host's real device count).
 
 Cost accounting: XLA's HloCostAnalysis counts a while-loop body ONCE,
 so the scanned layer stack hides (R-1)/R of the FLOPs and collectives.

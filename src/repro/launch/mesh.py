@@ -1,9 +1,7 @@
-"""Production mesh builders.
+"""Mesh builders for the launchers.
 
-A function, not a module-level constant: importing this module must not
+Functions, not module-level constants: importing this module must not
 touch jax device state (device count is locked at first jax init).
-Mesh construction goes through ``repro.dist.sharding.make_mesh`` so the
-same code runs on jax versions with and without ``AxisType``.
 """
 from __future__ import annotations
 
@@ -20,22 +18,6 @@ def make_production_mesh(*, multi_pod: bool = False):
 def make_local_mesh(data: int = 1, model: int = 1):
     """Smoke-test mesh over however many devices this host has."""
     return make_mesh((data, model), ("data", "model"))
-
-
-def resolve_workload(arch: str, *, production: bool = False,
-                     dp: int = 1, tp: int = 1, multi_pod: bool = False):
-    """Config-registry lookup + mesh construction in ONE place.
-
-    Every launcher used to hand-roll this pair; now ``launch/train``,
-    ``launch/serve`` and the WorkloadSpec loader all resolve an arch id
-    to ``(config, mesh)`` here: the full config on the production mesh
-    when ``production``, else the smoke config on a local
-    ``(dp, tp)`` mesh.
-    """
-    from repro.configs import registry
-    if production:
-        return registry.get(arch), make_production_mesh(multi_pod=multi_pod)
-    return registry.smoke(arch), make_local_mesh(dp, tp)
 
 
 # TPU v5e hardware constants (roofline targets).  Link bandwidths live

@@ -5,9 +5,12 @@ sampling) live in ``repro.serve.Engine``, which runs on the shared
 sharded-step API (``dist/steps.py``) — the same builders the dry-run
 lowers on the production mesh drive this local mesh.
 
-  PYTHONPATH=src python -m repro.launch.serve --arch yi-6b --smoke \
+  PYTHONPATH=src python -m repro.launch.serve --arch yi-6b-smoke \
       --prompt-len 32 --gen 16 --batch 4 [--dp 1 --tp 1] \
       [--temperature 0.8]
+
+``--arch`` is a registry id: ``yi-6b`` serves the published config,
+``yi-6b-smoke`` its reduced CPU-test config.
 
 or declaratively, from the same WorkloadSpec the operator applies:
 
@@ -45,12 +48,12 @@ def _apply_tuned_flags(arch: str, dp: int, tp: int, path: str) -> str:
 def main():
     from repro.configs import STRATEGIES
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default=None)
+    ap.add_argument("--arch", default=None,
+                    help="config registry id (e.g. yi-6b, yi-6b-smoke)")
     ap.add_argument("--spec", default=None,
                     help="declarative WorkloadSpec JSON (kind: serve); "
                          "engine shapes + request knobs come from the "
                          "spec")
-    ap.add_argument("--smoke", action="store_true")
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--gen", type=int, default=16)
     ap.add_argument("--batch", type=int, default=4)
@@ -83,7 +86,9 @@ def main():
         tuned = _apply_tuned_flags(args.arch, args.dp, args.tp,
                                    args.tuned_flags)
 
-    from repro.launch.mesh import resolve_workload
+    from repro.configs import registry
+    from repro.launch.cache import enable_compile_cache
+    from repro.launch.mesh import make_local_mesh
     from repro.serve import Engine, EngineConfig
     from repro.serve.paging import round_up
 
@@ -94,7 +99,6 @@ def main():
             f"launch.serve needs a serve spec, got kind={wspec.kind!r}"
         args.arch = wspec.arch
         strategy = wspec.resolved_strategy
-        cfg, mesh = resolve_workload(args.arch, dp=args.dp, tp=args.tp)
         s = wspec.serve
         args.batch = s.n_slots
         args.gen = s.max_new
@@ -105,13 +109,14 @@ def main():
     else:
         assert args.arch, "--arch or --spec is required"
         strategy = STRATEGIES[args.strategy]
-        cfg, mesh = resolve_workload(args.arch, dp=args.dp, tp=args.tp)
         ecfg = EngineConfig(
             n_slots=args.batch, page_size=args.page_size,
             max_prompt_len=round_up(args.prompt_len, args.page_size),
             max_seq_len=round_up(args.prompt_len + args.gen,
                                  args.page_size),
             prefill_chunk=args.prefill_chunk)
+    cfg, mesh = registry.get(args.arch), make_local_mesh(args.dp, args.tp)
+    enable_compile_cache()
     t_build = time.perf_counter()
     if args.replicas > 1:
         from repro.serve import Router

@@ -1,13 +1,15 @@
 """Training launcher.
 
-Smoke scale (this host):
-  PYTHONPATH=src python -m repro.launch.train --arch yi-6b --smoke \
+``--arch`` is a registry id: ``yi-6b`` trains the published config,
+``yi-6b-smoke`` its reduced CPU-test config:
+
+  PYTHONPATH=src python -m repro.launch.train --arch yi-6b-smoke \
       --steps 20 --batch 8 --seq 64
 
-Production scale: the same entry point with --production lowers the
-full config against the 16x16 production mesh (requires 256 devices —
-on real hardware the jax distributed runtime provides them; here the
-dry-run path in launch/dryrun.py is the no-hardware proof).
+--production runs on the 16x16 production mesh instead of a local one
+(requires 256 devices — on real hardware the jax distributed runtime
+provides them; here the dry-run path in launch/dryrun.py is the
+no-hardware proof).
 
 Elastic: --elastic splits the run into grow/shrink phases across this
 host's devices — the trainer checkpoints, reshards and resumes at each
@@ -15,7 +17,7 @@ transition (the same remesh path the operator's ElasticTrainExecutor
 drives from MiniCluster patch_size events):
 
   XLA_FLAGS=--xla_force_host_platform_device_count=8 \
-      PYTHONPATH=src python -m repro.launch.train --arch yi-6b --smoke \
+      PYTHONPATH=src python -m repro.launch.train --arch yi-6b-smoke \
       --elastic --steps 12 --batch 8 --seq 64
 """
 from __future__ import annotations
@@ -26,7 +28,8 @@ import jax
 
 from repro.configs import SHAPES, STRATEGIES, TrainConfig, registry
 from repro.configs.base import WorkloadShape
-from repro.launch.mesh import make_local_mesh, resolve_workload
+from repro.launch.cache import enable_compile_cache
+from repro.launch.mesh import make_local_mesh, make_production_mesh
 from repro.train import Trainer
 
 
@@ -40,16 +43,13 @@ def phase_steps(total: int, n_phases: int):
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default=None, choices=registry.ARCH_IDS
-                    + registry.EXTRA_IDS)
+    ap.add_argument("--arch", default=None, choices=registry.CONFIG_IDS)
     ap.add_argument("--spec", default=None,
                     help="declarative WorkloadSpec JSON (kind: train); "
                          "arch/steps/batch/seq/strategy/ckpt-dir come "
                          "from the spec, CLI flags override nothing")
-    ap.add_argument("--smoke", action="store_true",
-                    help="reduced config on local devices")
     ap.add_argument("--production", action="store_true",
-                    help="full config on the 16x16 production mesh")
+                    help="run on the 16x16 production mesh")
     ap.add_argument("--steps", type=int, default=20)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=64)
@@ -82,7 +82,9 @@ def main():
     else:
         assert args.arch, "--arch or --spec is required"
         strategy = STRATEGIES[args.strategy]
-    cfg, mesh = resolve_workload(args.arch, production=args.production)
+    cfg = registry.get(args.arch)
+    mesh = make_production_mesh() if args.production else make_local_mesh()
+    enable_compile_cache()
     if args.production:
         shape = SHAPES["train_4k"]
     else:

@@ -27,7 +27,7 @@ class PagedView(NamedTuple):
     """Block-table view over a paged KV pool (built by repro/serve).
 
     With a ``PagedView``, decode attention reads per-request pages out
-    of a shared ``(n_pages, page_size, kv, hd)`` pool instead of one
+    of a shared ``(n_pages, kv, page_size, hd)`` pool instead of one
     contiguous ``(batch, seq)`` cache; ``lengths`` doubles as the
     per-slot write position for the incoming token(s).
 
@@ -144,7 +144,7 @@ def attention_apply(cfg: ModelConfig, p, x, *, positions=None, causal=True,
 
     cache: dict(k=(B,Smax,KV,hd), v=...) for decode; ``cache_index`` is the
     scalar write position.  With ``paging`` the cache leaves are instead
-    page pools ``(n_pages, page_size, KV, hd)`` shared by all slots, and
+    page pools ``(n_pages, KV, page_size, hd)`` shared by all slots, and
     the write position is per-row (``paging.lengths``).  cross_kv:
     precomputed (k, v) from the encoder.  Returns (out, new_cache_kv | None).
     """
@@ -186,19 +186,21 @@ def attention_apply(cfg: ModelConfig, p, x, *, positions=None, causal=True,
             out = constrain(out, "act_batch", "act_seq_force", None, None)
         new_kv = (k, v)
     elif paging is not None and s == 1:                 # paged decode
-        page_size = cache["k"].shape[1]
+        page_size = cache["k"].shape[2]
         pos = paging.lengths                                       # (B,)
         page = paging.block_table[jnp.arange(b), pos // page_size]
         off = pos % page_size
-        ck = cache["k"].at[page, off].set(k[:, 0].astype(cache["k"].dtype))
-        cv = cache["v"].at[page, off].set(v[:, 0].astype(cache["v"].dtype))
-        ck = constrain(ck, None, None, "act_kv", None)
-        cv = constrain(cv, None, None, "act_kv", None)
+        ck = cache["k"].at[page, :, off].set(
+            k[:, 0].astype(cache["k"].dtype))
+        cv = cache["v"].at[page, :, off].set(
+            v[:, 0].astype(cache["v"].dtype))
+        ck = constrain(ck, None, "act_kv", None, None)
+        cv = constrain(cv, None, "act_kv", None, None)
         out = ops.paged_decode_attention(q, ck, cv, paging.block_table,
                                          pos + 1)
         new_kv = (ck, cv)
     elif paging is not None:                            # paged chunk prefill
-        page_size = cache["k"].shape[1]
+        page_size = cache["k"].shape[2]
         maxp = paging.block_table.shape[1]
         pos = paging.lengths                                       # (B,)
         offs = pos[:, None] + jnp.arange(s)[None, :]               # (B, s)
@@ -209,12 +211,14 @@ def attention_apply(cfg: ModelConfig, p, x, *, positions=None, causal=True,
         # the slot's reserved pages — never let them clamp onto a real
         # page and corrupt prompt KV)
         page = jnp.where(valid, page, paging.null_page)
-        ck = cache["k"].at[page, offs % page_size].set(
+        # (B, s) page and offset indices around the kv slice: the
+        # indexed view is (B, s, KV, hd), the layout of k and v
+        ck = cache["k"].at[page, :, offs % page_size].set(
             k.astype(cache["k"].dtype))
-        cv = cache["v"].at[page, offs % page_size].set(
+        cv = cache["v"].at[page, :, offs % page_size].set(
             v.astype(cache["v"].dtype))
-        ck = constrain(ck, None, None, "act_kv", None)
-        cv = constrain(cv, None, None, "act_kv", None)
+        ck = constrain(ck, None, "act_kv", None, None)
+        cv = constrain(cv, None, "act_kv", None, None)
         out = ops.paged_prefill_attention(q, ck, cv, paging.block_table,
                                           pos, paging.n_valid)
         new_kv = (ck, cv)

@@ -131,10 +131,13 @@ def cache_defs(cfg: ModelConfig, batch: int, seq_len: int,
 def paged_cache_defs(cfg: ModelConfig, n_slots: int, n_pages: int,
                      page_size: int, n_shards: int = 1):
     """Paged decode-state schema: attention KV lives in one shared page
-    pool per position (``(n_pages, page_size, kv, hd)``, indexed by the
+    pool per position (``(n_pages, kv, page_size, hd)``, indexed by the
     engine's block table; page 0 is the never-allocated null page), while
     seq-mixer states stay slot-major.  Sharding resolves through the same
-    ``cache_rules`` axis names as the contiguous cache.
+    ``cache_rules`` axis names as the contiguous cache.  One page of one
+    kv head is a contiguous ``(page_size, hd)`` tile — the block the
+    paged Pallas kernels read — and the page axis stays leading, so
+    ``pool[:, pages]`` slices whole pages.
 
     ``n_shards > 1`` marks the page dim with the logical ``pages`` axis
     so the pool shards over the data tier (slot-sharded page shards with
@@ -151,8 +154,8 @@ def paged_cache_defs(cfg: ModelConfig, n_slots: int, n_pages: int,
     for i, kind in enumerate(cfg.block_pattern):
         c = base[f"p{i}"]
         if kind == "attn":
-            c = {n: PDef((n_pages, page_size, kv, hd),
-                         (pages_ax, None, "kv_heads", None),
+            c = {n: PDef((n_pages, kv, page_size, hd),
+                         (pages_ax, "kv_heads", None, None),
                          init="zeros", dtype="bfloat16")
                  for n in ("k", "v")}
         out[f"p{i}"] = stack(c, cfg.n_repeats)

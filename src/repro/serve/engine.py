@@ -9,13 +9,14 @@ or decodes every in-flight slot, and finished requests are evicted so
 their pages are immediately reusable.  Token selection is temperature
 sampling (Gumbel-max), exact argmax at ``temperature == 0``.
 
-    eng = Engine(registry.smoke("yi-6b"), EngineConfig(n_slots=4))
+    eng = Engine(registry.get("yi-6b-smoke"), EngineConfig(n_slots=4))
     req = eng.submit([1, 2, 3], max_new_tokens=8)
     for tok in eng.stream(req):
         ...
 """
 from __future__ import annotations
 
+import functools
 from collections import OrderedDict
 from dataclasses import dataclass
 from typing import Iterator, Optional, Sequence
@@ -58,6 +59,15 @@ def sample_tokens(logits, temps, key):
     t = jnp.maximum(temps, 1e-6)[:, None]
     sampled = jnp.argmax(logits.astype(jnp.float32) / t + g, axis=-1)
     return jnp.where(temps > 0, sampled, greedy).astype(jnp.int32)
+
+
+def serving_weights(x, sharding):
+    """One parameter leaf as the engine holds it: bf16 (floating
+    leaves), on ``sharding``.  A host (numpy) leaf is cast on the host
+    and a device leaf on its own device, before the transfer."""
+    if jnp.issubdtype(x.dtype, jnp.floating) and x.dtype != jnp.bfloat16:
+        x = x.astype(jnp.bfloat16)
+    return jax.device_put(x, sharding)
 
 
 @dataclass(frozen=True)
@@ -198,13 +208,19 @@ class Engine:
         self._exact_prefill = cfg.sub_quadratic
         self._prefill_cache: OrderedDict = OrderedDict()
 
+        # weights are held in bf16, the compute dtype of every step, and
+        # made or placed straight onto this engine's devices: no float32
+        # copy and no unsharded copy ever sits on a chip
         if params is None:
-            params = Model(cfg).init(jax.random.PRNGKey(seed))
-        self.params = jax.tree_util.tree_map(
-            lambda x, s: jax.device_put(x, s), params, pshard)
-        self.pool = jax.tree_util.tree_map(
-            lambda x, s: jax.device_put(x, s),
-            paging.init_pool(cfg, ecfg.n_slots, layout), pool_sh)
+            params = jax.jit(functools.partial(Model(cfg).init,
+                                               dtype=jnp.bfloat16),
+                             out_shardings=pshard)(
+                jax.random.PRNGKey(seed))
+        self.params = jax.tree_util.tree_map(serving_weights, params,
+                                             pshard)
+        self.pool = jax.jit(
+            functools.partial(paging.init_pool, cfg, ecfg.n_slots, layout),
+            out_shardings=pool_sh)()
         self._next_token = np.zeros((ecfg.n_slots,), np.int32)
         self._key = jax.random.PRNGKey(seed + 1)
         # n_prefills counts prefill COMPUTE passes (one-shot prefills and

@@ -77,12 +77,12 @@ def scatter_prefill(cfg: ModelConfig, pool, pcache, page_rows, slots):
         if kind == "attn":
             new = {}
             for n in ("k", "v"):
-                dst = pool[key][n]          # (reps, n_pages, page, kv, hd)
+                dst = pool[key][n]          # (reps, n_pages, kv, page, hd)
                 src = pcache[key][n]        # (reps, B, prefill_len, kv, hd)
-                reps, b, pcap = src.shape[:3]
-                page = dst.shape[2]
-                src = src.reshape(reps, b, pcap // page, page,
-                                  *src.shape[3:])
+                reps, b, pcap, kv, hd = src.shape
+                page = dst.shape[3]
+                src = src.reshape(reps, b, pcap // page, page, kv, hd)
+                src = src.transpose(0, 1, 2, 4, 3, 5)
                 new[n] = dst.at[:, page_rows].set(src.astype(dst.dtype))
             out[key] = new
         else:

@@ -86,7 +86,9 @@ class WorkloadReconciler:
         if not errors:
             strategy = strategy if strategy is not None \
                 else spec.resolved_strategy
-            cfg = cfg if cfg is not None else self._registry_cfg(spec)
+            if cfg is None:
+                from repro.configs import registry
+                cfg = registry.get(spec.arch)
             errors = self._cluster_errors(spec, cfg, strategy)
         if errors:
             raise SpecError(errors)
@@ -112,11 +114,6 @@ class WorkloadReconciler:
         self.instance.clock.trace("workload_applied", jobid=job.jobid,
                                   workload=spec.kind, name=spec.name)
         return handle
-
-    @staticmethod
-    def _registry_cfg(spec: WorkloadSpec):
-        from repro.configs import registry
-        return registry.smoke(spec.arch)
 
     @staticmethod
     def _job_args(spec: WorkloadSpec) -> Dict[str, Any]:

@@ -236,11 +236,11 @@ class WorkloadSpec:
                              f"kind {self.kind!r} not in {KINDS}"))
         if known_arch:
             from repro.configs import registry
-            if self.arch not in registry.ARCH_IDS + registry.EXTRA_IDS:
+            if self.arch not in registry.CONFIG_IDS:
                 errs.append(_err(
                     "arch", "unknown-config",
                     f"unknown model config {self.arch!r}; known: "
-                    f"{registry.ARCH_IDS + registry.EXTRA_IDS}"))
+                    f"{registry.CONFIG_IDS}"))
         if isinstance(self.strategy, str):
             if self.strategy not in STRATEGIES:
                 errs.append(_err("strategy", "unknown-strategy",

@@ -189,15 +189,16 @@ def load_tuned(key: str, path: str = TUNED_FLAGS) -> Optional[Dict[str, str]]:
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="yi-6b")
+    ap.add_argument("--arch", default="yi-6b-smoke")
     ap.add_argument("--dp", type=int, default=1)
     ap.add_argument("--tp", type=int, default=1)
     ap.add_argument("--iters", type=int, default=10)
     ap.add_argument("--out", default=TUNED_FLAGS)
     args = ap.parse_args()
 
-    from repro.launch.mesh import resolve_workload
-    cfg, mesh = resolve_workload(args.arch, dp=args.dp, tp=args.tp)
+    from repro.configs import registry
+    from repro.launch.mesh import make_local_mesh
+    cfg, mesh = registry.get(args.arch), make_local_mesh(args.dp, args.tp)
     cell = sweep(cfg, mesh, iters=args.iters)
     key = tune_key(args.arch, mesh)
     record(key, cell, args.out)

@@ -224,7 +224,7 @@ def test_flux_allocation_runs_sharded_step_on_its_submesh():
     mc.create()
     mc.wait_ready()
     job = mc.instance.submit(JobSpec(n_nodes=2, walltime=1e9,
-                                     command="yi-6b"))
+                                     command="yi-6b-smoke"))
     clock.run(until=clock.now + 600)
     assert job.state == JobState.INACTIVE
     assert job.result == "completed"
@@ -256,9 +256,9 @@ def test_submesh_executor_places_same_shape_jobs_on_their_own_devices():
     mc.create()
     mc.wait_ready()
     j1 = mc.instance.submit(JobSpec(n_nodes=1, walltime=1e9,
-                                    command="yi-6b"))
+                                    command="yi-6b-smoke"))
     j2 = mc.instance.submit(JobSpec(n_nodes=1, walltime=1e9,
-                                    command="yi-6b"))
+                                    command="yi-6b-smoke"))
     clock.run(until=clock.now + 600)
     assert j1.result == "completed" and j2.result == "completed"
     ids1 = executor.ran[j1.jobid]["device_ids"]

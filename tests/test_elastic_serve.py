@@ -17,7 +17,6 @@ from repro.configs.base import ModelConfig
 from repro.core import (FluxMiniCluster, JobState, MiniClusterSpec,
                         NetModel, ResourceGraph, SimClock)
 from repro.dist.sharding import make_mesh
-from repro.models import Model
 from repro.serve import Engine, EngineConfig
 from repro.spec import ResourceSpec, ServeSpec, WorkloadSpec
 
@@ -44,15 +43,12 @@ def _run_until(clock, cond, horizon=100_000.0):
     assert cond(), "sim condition not reached within horizon"
 
 
-def _params():
-    return Model(TINY).init(jax.random.PRNGKey(0))
-
-
 def _reference_tokens(mesh_shape, temperature=0.0):
-    """Uninterrupted run: same prompts, same submission tick."""
+    """Uninterrupted run: same prompts, same submission tick, and the
+    same weights (the executor's engines make them from seed 0 too)."""
     mesh = make_mesh(mesh_shape, ("data", "model"),
                      devices=jax.devices()[:mesh_shape[0] * mesh_shape[1]])
-    eng = Engine(TINY, ECFG, mesh=mesh, params=_params(), seed=0)
+    eng = Engine(TINY, ECFG, mesh=mesh, seed=0)
     first = [eng.submit(p, max_new_tokens=GEN, temperature=temperature)
              for p in FIRST]
     for _ in range(TICKS_BEFORE_RESIZE):
@@ -230,7 +226,7 @@ def test_cluster_shrink_evicting_same_size_job_is_lossless():
     assert job.requeues >= 1
     assert rec["hosts"] == [0, 1]      # re-placed after the blocker left
     mesh = make_mesh((2, 2), ("data", "model"), devices=jax.devices()[:4])
-    eng = Engine(TINY, ECFG, mesh=mesh, params=_params(), seed=0)
+    eng = Engine(TINY, ECFG, mesh=mesh, seed=0)
     reqs = [eng.submit(p, max_new_tokens=GEN) for p in FIRST]
     eng.run()
     assert rec["tokens"] == [r.tokens for r in reqs], \
@@ -269,7 +265,7 @@ def test_shrink_that_spares_the_allocation_resumes_in_place():
     # tokens still match the uninterrupted reference (no mid-resize
     # submissions in this scenario, so the reference skips them too)
     mesh = make_mesh((2, 2), ("data", "model"), devices=jax.devices()[:4])
-    eng = Engine(TINY, ECFG, mesh=mesh, params=_params(), seed=0)
+    eng = Engine(TINY, ECFG, mesh=mesh, seed=0)
     reqs = [eng.submit(p, max_new_tokens=GEN) for p in FIRST]
     eng.run()
     assert rec["tokens"] == [r.tokens for r in reqs]

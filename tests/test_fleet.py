@@ -255,7 +255,7 @@ def _fleet_spec(**serve_kw):
               max_seq_len=16, n_requests=4, prefill_chunk=8, replicas=2,
               tenant="acme", ttft_slo_s=0.5)
     kw.update(serve_kw)
-    return WorkloadSpec(kind="serve", arch="yi-6b", name="fleet",
+    return WorkloadSpec(kind="serve", arch="yi-6b-smoke", name="fleet",
                         resources=ResourceSpec(n_nodes=1, pod_local=True),
                         serve=ServeSpec(**kw))
 
@@ -269,7 +269,7 @@ def test_spec_validates_fleet_fields():
     errs = _fleet_spec(ttft_slo_s=-1.0).errors()
     assert any(e["field"] == "serve.ttft_slo_s" for e in errs)
     # elastic + replicas > 1 is the live-resizable fleet (PR 10): valid
-    ok = WorkloadSpec(kind="serve", arch="yi-6b",
+    ok = WorkloadSpec(kind="serve", arch="yi-6b-smoke",
                       resources=ResourceSpec(n_nodes=1, elastic=True),
                       serve=ServeSpec(replicas=2))
     assert ok.errors() == []
@@ -305,7 +305,7 @@ def test_fleet_demand_policy_resizes_live_fleet_end_to_end():
         pytest.skip("needs 8 sim devices")
     clock, mc = _mini_cluster(size=2, max_size=3)
     spec = WorkloadSpec(
-        kind="serve", arch="yi-6b", name="live-fleet",
+        kind="serve", arch="yi-6b-smoke", name="live-fleet",
         resources=ResourceSpec(n_nodes=1, elastic=True),
         serve=ServeSpec(n_slots=2, max_new=6, page_size=8,
                         max_prompt_len=8, max_seq_len=16,

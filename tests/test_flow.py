@@ -60,7 +60,7 @@ def _dryrun(name="d", n_nodes=1):
                         resources=ResourceSpec(n_nodes=n_nodes))
 
 
-def _train(total_steps=4, arch="yi-6b"):
+def _train(total_steps=4, arch="yi-6b-smoke"):
     return WorkloadSpec(
         kind="train", arch=arch, name="flow-train",
         resources=ResourceSpec(n_nodes=2, elastic=True),
@@ -68,7 +68,7 @@ def _train(total_steps=4, arch="yi-6b"):
                         seq_len=32, chunk_steps=2))
 
 
-def _fleet(arch="yi-6b", replicas=2, n_requests=4):
+def _fleet(arch="yi-6b-smoke", replicas=2, n_requests=4):
     return WorkloadSpec(
         kind="serve", arch=arch, name="flow-fleet",
         resources=ResourceSpec(n_nodes=1, elastic=True),

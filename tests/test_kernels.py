@@ -83,11 +83,23 @@ def test_decode_pallas_matches_oracle(smax, fill, h, hkv):
                                rtol=2e-5, atol=2e-5)
 
 
+def _contiguous(pool, bt):
+    """Each block-table row's pages, token-major: (B, maxp * page, hkv,
+    d)."""
+    rows = []
+    for r in range(bt.shape[0]):
+        pages = [np.asarray(pool[int(p)]).transpose(1, 0, 2)
+                 for p in np.asarray(bt[r])]
+        rows.append(np.concatenate(pages, axis=0))
+    return jnp.asarray(np.stack(rows))
+
+
 def _mk_paged(b, n_pages, page, maxp, hkv, h, d, fills, seed=0):
-    """Random pool + a block table whose rows own disjoint pages."""
+    """Random (n_pages, hkv, page, d) pool + a block table whose rows
+    own disjoint pages."""
     ks = jax.random.split(jax.random.PRNGKey(seed), 3)
-    kp = jax.random.normal(ks[0], (n_pages, page, hkv, d), jnp.float32)
-    vp = jax.random.normal(ks[1], (n_pages, page, hkv, d), jnp.float32)
+    kp = jax.random.normal(ks[0], (n_pages, hkv, page, d), jnp.float32)
+    vp = jax.random.normal(ks[1], (n_pages, hkv, page, d), jnp.float32)
     q = jax.random.normal(ks[2], (b, 1, h, d), jnp.float32)
     bt = np.zeros((b, maxp), np.int32)
     nxt = 1                                 # page 0 is the null page
@@ -106,8 +118,8 @@ def test_paged_decode_ref_matches_gathered_oracle(h, hkv):
     q, kp, vp, bt, fills = _mk_paged(b, 16, page, maxp, hkv, h, d,
                                      fills=[64, 33, 1])
     out = dec_ref.paged_decode_ref(q, kp, vp, bt, fills)
-    k = kp[bt].reshape(b, maxp * page, hkv, d)
-    v = vp[bt].reshape(b, maxp * page, hkv, d)
+    k = _contiguous(kp, bt)
+    v = _contiguous(vp, bt)
     for r in range(b):
         ref = dec_ref.decode_ref(q[r:r + 1], k[r:r + 1], v[r:r + 1],
                                  int(fills[r]))
@@ -139,8 +151,8 @@ def test_paged_prefill_ref_matches_whole_prompt_oracle(h, hkv, start,
     b, page, maxp, d, chunk = 2, 8, 4, 32, 8
     total = start + chunk
     ks = jax.random.split(jax.random.PRNGKey(3), 3)
-    kp = jax.random.normal(ks[0], (16, page, hkv, d), jnp.float32)
-    vp = jax.random.normal(ks[1], (16, page, hkv, d), jnp.float32)
+    kp = jax.random.normal(ks[0], (16, hkv, page, d), jnp.float32)
+    vp = jax.random.normal(ks[1], (16, hkv, page, d), jnp.float32)
     q = jax.random.normal(ks[2], (b, chunk, h, d), jnp.float32)
     bt = np.zeros((b, maxp), np.int32)
     nxt = 1
@@ -152,8 +164,8 @@ def test_paged_prefill_ref_matches_whole_prompt_oracle(h, hkv, start,
     starts = jnp.full((b,), start, jnp.int32)
     n_valid = jnp.full((b,), valid, jnp.int32)
     out = dec_ref.paged_prefill_ref(q, kp, vp, bt, starts, n_valid)
-    kg = kp[bt].reshape(b, maxp * page, hkv, d)[:, :total]
-    vg = vp[bt].reshape(b, maxp * page, hkv, d)[:, :total]
+    kg = _contiguous(kp, bt)[:, :total]
+    vg = _contiguous(vp, bt)[:, :total]
     # oracle: full causal flash over [0, total) with the chunk's q rows
     qf = jnp.zeros((b, total, h, d), jnp.float32)
     qf = qf.at[:, start:].set(q)
@@ -166,8 +178,8 @@ def test_paged_prefill_pallas_matches_ref():
     b, page, maxp, d, h, hkv, chunk = 2, 8, 4, 32, 4, 2, 8
     start, valid = 5, 8
     ks = jax.random.split(jax.random.PRNGKey(7), 3)
-    kp = jax.random.normal(ks[0], (16, page, hkv, d), jnp.float32)
-    vp = jax.random.normal(ks[1], (16, page, hkv, d), jnp.float32)
+    kp = jax.random.normal(ks[0], (16, hkv, page, d), jnp.float32)
+    vp = jax.random.normal(ks[1], (16, hkv, page, d), jnp.float32)
     q = jax.random.normal(ks[2], (b, chunk, h, d), jnp.float32)
     bt = np.zeros((b, maxp), np.int32)
     nxt = 1
@@ -197,6 +209,22 @@ def test_rmsnorm_pallas_matches_oracle(rows, d, dtype):
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32),
                                rtol=tol, atol=tol)
+
+
+def test_rmsnorm_pallas_gradients_match_ref():
+    """The kernel trains: its custom VJP gives the ref's gradients."""
+    from repro.kernels.rmsnorm import ref as rn_ref
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 16, 64), jnp.float32)
+    w = jax.random.normal(jax.random.PRNGKey(1), (64,), jnp.float32)
+
+    def loss(fn):
+        return lambda x_, w_: (fn(x_, w_) ** 2).sum()
+    want = jax.grad(loss(rn_ref.rmsnorm_ref), argnums=(0, 1))(x, w)
+    got = jax.grad(loss(lambda a, b: ops.rmsnorm(a, b, impl="interpret")),
+                   argnums=(0, 1))(x, w)
+    for g, r in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r),
+                                   rtol=1e-5, atol=1e-5)
 
 
 def test_decode_kernel_consistent_with_flash_prefill():

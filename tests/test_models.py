@@ -16,7 +16,7 @@ ARCHS = registry.ARCH_IDS
 
 @pytest.mark.parametrize("arch", ARCHS)
 def test_train_step_shapes_and_finite(arch):
-    cfg = registry.smoke(arch)
+    cfg = registry.get(f"{arch}-smoke")
     model = Model(cfg)
     params = model.init(jax.random.PRNGKey(0))
     batch = example_batch(cfg, WorkloadShape("t", "train", 16, 2))
@@ -32,7 +32,7 @@ def test_train_step_shapes_and_finite(arch):
 
 @pytest.mark.parametrize("arch", ARCHS)
 def test_prefill_then_decode_finite(arch):
-    cfg = registry.smoke(arch)
+    cfg = registry.get(f"{arch}-smoke")
     model = Model(cfg)
     params = model.init(jax.random.PRNGKey(0))
     pb = example_batch(cfg, WorkloadShape("p", "prefill", 16, 2))
@@ -54,7 +54,7 @@ def test_decode_matches_teacher_forced_forward(arch):
     padded prefill legitimately changes which tokens are dropped — a
     known batch-composition sensitivity of capacity-based MoE serving.
     """
-    cfg = registry.smoke(arch)
+    cfg = registry.get(f"{arch}-smoke")
     model = Model(cfg)
     params = model.init(jax.random.PRNGKey(1), jnp.float32)
     P, S = 12, 16
@@ -96,7 +96,7 @@ def test_decode_matches_teacher_forced_forward(arch):
 
 
 def test_vision_patches_change_output():
-    cfg = registry.smoke("pixtral-12b")
+    cfg = registry.get("pixtral-12b-smoke")
     model = Model(cfg)
     params = model.init(jax.random.PRNGKey(0))
     batch = example_batch(cfg, WorkloadShape("t", "train", 16, 2))
@@ -107,7 +107,7 @@ def test_vision_patches_change_output():
 
 
 def test_whisper_frames_change_output():
-    cfg = registry.smoke("whisper-base")
+    cfg = registry.get("whisper-base-smoke")
     model = Model(cfg)
     params = model.init(jax.random.PRNGKey(0))
     batch = example_batch(cfg, WorkloadShape("t", "train", 16, 2))

@@ -29,6 +29,14 @@ ECFG = EngineConfig(n_slots=2, page_size=4, max_seq_len=32,
                     max_prompt_len=8)
 
 
+def _weights(cfg):
+    """The seed-0 weights cast to bf16, as an engine holds them, so the
+    contiguous references below compute on exactly the engine's
+    weights."""
+    return jax.tree_util.tree_map(lambda x: x.astype(jnp.bfloat16),
+                                  Model(cfg).init(jax.random.PRNGKey(0)))
+
+
 def _mesh_2x4():
     if len(jax.devices()) < 8:
         pytest.skip("needs 8 devices (conftest forces them)")
@@ -153,7 +161,7 @@ def test_paged_matches_contiguous_single_device(cfg):
     # mamba recurrence), so their reference prefills unpadded too
     ref = (_contiguous_greedy_exact if cfg.sub_quadratic
            else _contiguous_greedy)
-    params = Model(cfg).init(jax.random.PRNGKey(0))
+    params = _weights(cfg)
     eng = Engine(cfg, ECFG, params=params)
     r1 = eng.submit([1, 2, 3, 4, 5], max_new_tokens=6)
     r2 = eng.submit([7, 8, 9], max_new_tokens=6)
@@ -164,7 +172,7 @@ def test_paged_matches_contiguous_single_device(cfg):
 
 def test_paged_matches_contiguous_on_8dev_mesh():
     mesh = _mesh_2x4()
-    params = Model(TINY).init(jax.random.PRNGKey(0))
+    params = _weights(TINY)
     eng = Engine(TINY, EngineConfig(n_slots=4, page_size=4, max_seq_len=32,
                                     max_prompt_len=8),
                  strategy=BASELINE, mesh=mesh, params=params)
@@ -179,7 +187,7 @@ def test_continuous_batching_admits_mid_decode():
     """ISSUE acceptance: a request admitted while others are mid-decode
     completes with greedy output identical to running it alone through
     the contiguous-cache path."""
-    params = Model(TINY).init(jax.random.PRNGKey(0))
+    params = _weights(TINY)
     eng = Engine(TINY, ECFG, params=params)
     early = eng.submit([1, 2, 3, 4, 5], max_new_tokens=8)
     eng.step()                              # prefill
@@ -197,7 +205,7 @@ def test_eviction_frees_pages_and_reuse_stays_correct():
     """Page pressure: the second request cannot be admitted until the
     first finishes and is evicted; its decode then runs on the recycled
     pages and must still match the contiguous path."""
-    params = Model(TINY).init(jax.random.PRNGKey(0))
+    params = _weights(TINY)
     ecfg = EngineConfig(n_slots=2, page_size=4, max_seq_len=16,
                         max_prompt_len=8, n_pages=5)   # 4 usable pages
     eng = Engine(TINY, ecfg, params=params)
@@ -240,7 +248,7 @@ def test_sample_tokens_mixed_temperatures():
 
 
 def test_engine_temperature_threading_is_seeded():
-    params = Model(TINY).init(jax.random.PRNGKey(0))
+    params = _weights(TINY)
 
     def run(seed):
         eng = Engine(TINY, ECFG, params=params, seed=seed)
@@ -348,7 +356,7 @@ def test_paged_matches_contiguous_at_temperature():
     """ISSUE satellite: the paged==contiguous invariant extended past
     greedy — identical PRNG key => token-for-token identical sampled
     streams, with a second request admitted mid-decode."""
-    params = Model(TINY).init(jax.random.PRNGKey(0))
+    params = _weights(TINY)
     eng = Engine(TINY, ECFG, params=params, seed=3)
     ref = _ContiguousSampler(TINY, params, ECFG.n_slots, seed=3)
 
@@ -395,7 +403,7 @@ def test_chunked_prefill_greedy_identical_to_legacy(chunk):
     chunked engine's outputs are token-for-token identical to the
     legacy prefill-then-decode engine — including a request admitted
     mid-decode whose prompt trickles in across several mixed ticks."""
-    params = Model(TINY).init(jax.random.PRNGKey(0))
+    params = _weights(TINY)
     ecfg = EngineConfig(n_slots=2, page_size=4, max_seq_len=32,
                         max_prompt_len=8, prefill_chunk=chunk)
     eng = Engine(TINY, ecfg, params=params)
@@ -417,7 +425,7 @@ def test_chunked_prefill_greedy_identical_to_legacy(chunk):
 def test_chunked_prefill_page_reuse_stays_correct():
     """Evicted pages re-used by a chunked prefill still decode exactly:
     the second request's chunks land on the first's recycled pages."""
-    params = Model(TINY).init(jax.random.PRNGKey(0))
+    params = _weights(TINY)
     ecfg = EngineConfig(n_slots=2, page_size=4, max_seq_len=16,
                         max_prompt_len=8, n_pages=5,     # 4 usable pages
                         prefill_chunk=2)
@@ -440,7 +448,7 @@ def test_chunked_admission_mid_decode_at_temperature():
     prefill at temperature>0 — lockstep against the contiguous sampler
     driven with the engine's exact key stream (mixed ticks consume one
     key each, like any other tick)."""
-    params = Model(TINY).init(jax.random.PRNGKey(0))
+    params = _weights(TINY)
     ecfg = EngineConfig(n_slots=2, page_size=4, max_seq_len=32,
                         max_prompt_len=8, prefill_chunk=2)
     eng = Engine(TINY, ecfg, params=params, seed=3)
@@ -485,7 +493,7 @@ def test_chunked_prefill_falls_back_for_seq_mixers():
     """Seq-mixer recurrences cannot skip chunk padding: a hybrid engine
     with prefill_chunk set must silently keep exact prefill-then-decode
     and still match its reference."""
-    params = Model(TINY_HYBRID).init(jax.random.PRNGKey(0))
+    params = _weights(TINY_HYBRID)
     ecfg = EngineConfig(n_slots=2, page_size=4, max_seq_len=32,
                         max_prompt_len=8, prefill_chunk=2)
     eng = Engine(TINY_HYBRID, ecfg, params=params)
@@ -543,7 +551,7 @@ def test_paged_matches_contiguous_dp_sharded_pool():
     page pool and block table sharded over the data axis of a (2, 4)
     mesh — legacy and chunked engines both."""
     mesh = _mesh_2x4()
-    params = Model(TINY).init(jax.random.PRNGKey(0))
+    params = _weights(TINY)
     prompts = [[1, 2, 3, 4, 5], [7, 8, 9], [11, 12, 13, 14], [2, 4]]
     want = [_contiguous_greedy(TINY, params, p, 5) for p in prompts]
     for chunk in (0, 3):
@@ -565,7 +573,7 @@ def test_paged_matches_contiguous_dp_sharded_pool():
 def test_prefill_compile_cache_is_lru_bounded():
     """Seq-mixer archs compile per exact prompt length; the LRU cap
     bounds that and the stats surface hits/misses/evictions."""
-    params = Model(TINY_HYBRID).init(jax.random.PRNGKey(0))
+    params = _weights(TINY_HYBRID)
     ecfg = EngineConfig(n_slots=2, page_size=4, max_seq_len=32,
                         max_prompt_len=8, prefill_cache_cap=2)
     eng = Engine(TINY_HYBRID, ecfg, params=params)
@@ -577,7 +585,7 @@ def test_prefill_compile_cache_is_lru_bounded():
     assert pc["size"] <= 2 and pc["cap"] == 2
     assert pc["misses"] == 4 and pc["evictions"] >= 2 and pc["hits"] == 0
     # attention archs share ONE padded compile: all hits after the first
-    eng2 = Engine(TINY, ecfg, params=Model(TINY).init(jax.random.PRNGKey(0)))
+    eng2 = Engine(TINY, ecfg, params=_weights(TINY))
     for plen in (2, 3, 4):
         eng2.submit(list(range(1, plen + 1)), max_new_tokens=2)
     eng2.run()
@@ -591,7 +599,7 @@ def test_prefill_compile_cache_is_lru_bounded():
 
 
 def test_stream_yields_tokens_and_advances_other_requests():
-    params = Model(TINY).init(jax.random.PRNGKey(0))
+    params = _weights(TINY)
     eng = Engine(TINY, ECFG, params=params)
     a = eng.submit([1, 2, 3, 4], max_new_tokens=5)
     b = eng.submit([5, 6], max_new_tokens=5)
@@ -625,7 +633,7 @@ def test_minicluster_allocation_hosts_serve_engine():
     mc.create()
     mc.wait_ready()
     job = mc.instance.submit(JobSpec(n_nodes=2, walltime=1e9,
-                                     command="tiny",
+                                     command="lammps-proxy-smoke",
                                      args={"max_new": 3}))
     clock.run(until=clock.now + 600)
     assert job.state == JobState.INACTIVE
@@ -648,7 +656,7 @@ def test_stats_page_conservation_dp_sharded():
     free_pages + pages_in_use must equal the usable pool (n_pages minus
     one null page per shard)."""
     mesh = _mesh_2x4()
-    params = Model(TINY).init(jax.random.PRNGKey(0))
+    params = _weights(TINY)
     ecfg = EngineConfig(n_slots=4, page_size=4, max_seq_len=32,
                         max_prompt_len=8, dp_shards=2)
     eng = Engine(TINY, ecfg, strategy=BASELINE, mesh=mesh, params=params)
@@ -679,7 +687,7 @@ def test_ttft_stamped_at_submit_not_construction():
 
 
 def test_ttft_excludes_pre_submit_hold_on_engine():
-    params = Model(TINY).init(jax.random.PRNGKey(0))
+    params = _weights(TINY)
     eng = Engine(TINY, ECFG, params=params)
     import time as _time
     req = Request(prompt=[1, 2, 3], max_new_tokens=2)
@@ -695,7 +703,7 @@ def test_stream_raises_on_foreign_request():
     """Streaming a request the engine does not own must raise a
     structured StreamError, not silently end the iterator."""
     from repro.serve import StreamError
-    params = Model(TINY).init(jax.random.PRNGKey(0))
+    params = _weights(TINY)
     a = Engine(TINY, ECFG, params=params)
     b = Engine(TINY, ECFG, params=params)
     req = a.submit([1, 2, 3], max_new_tokens=4)

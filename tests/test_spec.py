@@ -116,9 +116,9 @@ def test_loader_checks_committed_specs(tmp_path):
     from repro.spec import check_spec, load_spec
     good = tmp_path / "good.json"
     good.write_text(json.dumps(WorkloadSpec(
-        kind="serve", arch="yi-6b", name="ok").to_dict()))
+        kind="serve", arch="yi-6b-smoke", name="ok").to_dict()))
     spec, errors = check_spec(str(good))
-    assert errors == [] and spec.arch == "yi-6b"
+    assert errors == [] and spec.arch == "yi-6b-smoke"
     assert load_spec(str(good)).name == "ok"
 
     bad = tmp_path / "bad.json"
@@ -135,7 +135,7 @@ def test_wrong_typed_values_lint_as_structured_errors(tmp_path):
     from repro.spec import check_spec
     drifted = tmp_path / "drifted.json"
     drifted.write_text(json.dumps({
-        "kind": "serve", "arch": "yi-6b",
+        "kind": "serve", "arch": "yi-6b-smoke",
         "resources": {"n_nodes": "4"},
         "serve": {"n_slots": "two", "temperature": "hot"}}))
     spec, errors = check_spec(str(drifted))
@@ -163,7 +163,7 @@ def test_non_string_strategy_value_lints_as_structured_error():
 def test_serve_errors_reports_every_bad_field():
     """One SpecError lists EVERY independent bad value, not just the
     first (the collect-everything contract)."""
-    spec = WorkloadSpec(kind="serve", arch="yi-6b",
+    spec = WorkloadSpec(kind="serve", arch="yi-6b-smoke",
                         serve=ServeSpec(n_slots=0, max_new=0,
                                         temperature=-1.0))
     fields = {e["field"] for e in spec.errors()}

@@ -14,7 +14,7 @@ from repro.configs.base import WorkloadShape
 
 def test_data_pipeline_deterministic_and_disjoint():
     from repro.data import DataPipeline, synthetic_batch
-    cfg = registry.smoke("yi-6b")
+    cfg = registry.get("yi-6b-smoke")
     shape = WorkloadShape("t", "train", 32, 8)
     # determinism: same (seed, step) -> same batch
     b1 = synthetic_batch(cfg, shape, seed=5, step=3)
@@ -81,7 +81,7 @@ def test_optimizers_reduce_quadratic_loss(optname):
     from repro.optim import make_optimizer, opt_state_defs
     from repro.models.params import PDef, init_params, abstract_params
     import dataclasses
-    cfg = dataclasses.replace(registry.smoke("yi-6b"), optimizer=optname)
+    cfg = dataclasses.replace(registry.get("yi-6b-smoke"), optimizer=optname)
     tcfg = TrainConfig(learning_rate=0.05, warmup_steps=0,
                        total_steps=100, weight_decay=0.0, grad_clip=1e9)
     defs = {"w": PDef((4, 8), ("embed", "ff"))}
