@@ -18,11 +18,21 @@ Metric names follow Prometheus convention: ``<tier>_<what>_total`` for
 counters, plain ``<tier>_<what>`` for gauges, ``<tier>_<what>_s`` for
 second-valued histograms.  The ROADMAP "Observability contract" lists
 the registered names.
+
+:func:`watch_compiles` adds a process-wide count of XLA compilations
+(:func:`compile_count`, :func:`compile_times`): JAX reports each
+executable it builds or loads from its persistent cache as one
+``/jax/core/compile/backend_compile_duration`` event (the
+``jaxpr_trace_duration`` event fires once per traced primitive as well,
+so it is no count of programs).  An engine reads the count around each
+launch to put a recompile on its program and tick.
 """
 from __future__ import annotations
 
 import bisect
 from typing import Any, Dict, Iterable, List, Optional, Tuple
+
+from repro.obs.trace import WALL
 
 LabelKey = Tuple[Tuple[str, str], ...]
 
@@ -176,3 +186,36 @@ class MetricsRegistry:
                     else:
                         dst[kk] = {**h, "buckets": list(h["buckets"])}
         return out
+
+
+# -- compilations -----------------------------------------------------------
+
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_compile_times: List[float] = []      # wall-clock end of each compile
+_watching = False
+
+
+def _on_duration(event: str, duration: float, **kwargs) -> None:
+    if event == COMPILE_EVENT:
+        _compile_times.append(WALL.now())
+
+
+def watch_compiles() -> None:
+    """Start counting compilations (once per process; later calls do
+    nothing)."""
+    global _watching
+    if not _watching:
+        import jax
+        jax.monitoring.register_event_duration_secs_listener(_on_duration)
+        _watching = True
+
+
+def compile_count() -> int:
+    """Compilations since :func:`watch_compiles` first ran."""
+    return len(_compile_times)
+
+
+def compile_times() -> Tuple[float, ...]:
+    """When each counted compilation ended, on the wall clock
+    (``obs.trace.WallClock``)."""
+    return tuple(_compile_times)

@@ -16,6 +16,13 @@ spans at finish time — so a disabled tracer (the default: ``tracer is
 None``) costs the hot path nothing beyond attribute stamps it already
 made.
 
+Live work is bracketed with :func:`phase`: a span that is always a
+``jax.profiler`` annotation on the profiler's own host clock (so a
+profile of the run shows it beside the device's operations) and, when
+a tracer is given, also a :class:`Span` on the tracer's clock.  The
+engine's ticks are the trace ``tick-<n>``: one ``engine.step`` span
+whose children are its phases (``ENGINE_PHASES``).
+
 Clock-injection rule (the ROADMAP "Observability contract"): every
 component that stamps timing takes a ``Clock`` and calls
 ``clock.now()``; nothing below the launch/bench layer calls
@@ -91,6 +98,47 @@ class Span:
 # the spans whose durations telescope to ttft_e2e (t_created..t_first)
 TTFT_SPANS = ("router_hold", "queue_wait", "prefill", "first_decode")
 REQUEST_SPANS = TTFT_SPANS + ("decode",)
+# the children of each ``engine.step`` span, in the order they run
+ENGINE_PHASES = ("engine.admit", "engine.plan", "engine.launch",
+                 "engine.sync", "engine.emit", "engine.metrics")
+
+
+def phase(tracer: Optional["Tracer"], name: str, trace: str, **attrs):
+    """``with phase(tracer, name, trace, **attrs) as p:`` brackets live
+    work as one span.
+
+    The span is always a ``jax.profiler.TraceAnnotation`` (about a
+    microsecond when no profile is being taken), and with a ``tracer``
+    also a :class:`Span` on ``tracer.clock``.  ``p.set_metadata(**attrs)``
+    adds attributes known only once the work is under way.
+    """
+    from jax.profiler import TraceAnnotation
+    ann = TraceAnnotation(name, **attrs)
+    return ann if tracer is None else _TracedPhase(tracer, name, trace,
+                                                   ann, attrs)
+
+
+class _TracedPhase:
+    """A :func:`phase` with a tracer: the annotation plus its Span."""
+
+    __slots__ = ("tracer", "name", "trace", "ann", "attrs", "span")
+
+    def __init__(self, tracer, name, trace, ann, attrs):
+        self.tracer, self.name, self.trace = tracer, name, trace
+        self.ann, self.attrs = ann, attrs
+
+    def __enter__(self) -> "_TracedPhase":
+        self.ann.__enter__()
+        self.span = self.tracer.begin(self.name, self.trace, **self.attrs)
+        return self
+
+    def set_metadata(self, **attrs) -> None:
+        self.ann.set_metadata(**attrs)
+        self.span.attrs.update(attrs)
+
+    def __exit__(self, *exc) -> None:
+        self.tracer.end(self.span)
+        self.ann.__exit__(*exc)
 
 
 class Tracer:

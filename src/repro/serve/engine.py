@@ -30,8 +30,8 @@ from repro.configs.base import ModelConfig, ShardingStrategy, WorkloadShape
 from repro.dist import sharding as shd
 from repro.dist import steps as dsteps
 from repro.models.model import Model
-from repro.obs.metrics import MetricsRegistry
-from repro.obs.trace import Clock, Tracer, WallClock
+from repro.obs.metrics import MetricsRegistry, compile_count, watch_compiles
+from repro.obs.trace import Clock, Tracer, WallClock, phase
 from repro.serve import paging
 from repro.serve.scheduler import Request, Scheduler, StreamError
 
@@ -118,7 +118,10 @@ class Engine:
     ``self.metrics`` (an ``obs.MetricsRegistry``; the legacy
     ``n_prefills``-style attributes are shims over it), and an optional
     ``self.tracer`` records each finished request's lifecycle spans.
-    ``tracer=None`` (default) keeps the hot path untraced.
+    ``tracer=None`` (default) keeps the hot path untraced.  Each tick
+    is also a tree of ``jax.profiler`` annotations (see :meth:`step`),
+    and ``serve_compiles_total{program}`` counts the compilations that
+    happened inside a launch.
     """
 
     # legacy counter attributes, backed by the metrics registry
@@ -233,6 +236,8 @@ class Engine:
         self.n_mixed_steps = 0
         self.n_generated = 0
         self.prefix_cache = None      # set by a fleet Router (fleet.py)
+        self._tick = 0                # working step() calls: span labels
+        watch_compiles()
 
     # -- park / adopt (elastic + canary promotion machinery) ----------------
     def snapshot_state(self) -> dict:
@@ -351,35 +356,63 @@ class Engine:
         slot is mid-prefill the tick is *mixed* — one prompt chunk for
         the head admitting slot fused with a single-token decode of
         every fully prefilled slot.
+
+        Each tick is one ``engine.step`` span (trace ``tick-<n>``, with
+        ``tick`` and ``kind``) around its phases, ``obs.ENGINE_PHASES``;
+        a one-shot tick repeats plan to metrics for each admitted
+        request.
         """
-        admitted = self.scheduler.admit()
-        if admitted and self.prefix_cache is not None and self._chunked:
-            # fleet prefix cache: copy cached pages for the longest
-            # page-aligned common prompt prefix into the slot's own
-            # pages (copy-on-adopt) and skip those prompt tokens
-            for req in admitted:
-                self.prefix_cache.adopt(self, req)
-        if self._chunked:
-            nxt = self.scheduler.next_chunk()
-            if nxt is not None:
-                self._run_mixed(*nxt)
-                return True
-            if self.scheduler.running:
-                self._run_decode()
-                return True
+        sch = self.scheduler
+        if not sch.has_work:
             return False
-        if admitted:
-            for req in admitted:
-                self._run_prefill(req)
-            return True
-        if self.scheduler.running:
-            self._run_decode()
-            return True
-        return False
+        tr, n = self.tracer, self._tick
+        trace = f"tick-{n}"
+        with phase(tr, "engine.step", trace, tick=n) as tick:
+            with phase(tr, "engine.admit", trace):
+                admitted = sch.admit()
+                if admitted and self.prefix_cache is not None \
+                        and self._chunked:
+                    # fleet prefix cache: copy cached pages for the
+                    # longest page-aligned common prompt prefix into the
+                    # slot's own pages (copy-on-adopt) and skip those
+                    # prompt tokens
+                    for req in admitted:
+                        self.prefix_cache.adopt(self, req)
+            nxt = sch.next_chunk() if self._chunked else None
+            if nxt is not None:
+                tick.set_metadata(kind="mixed")
+                self._run_mixed(*nxt, trace)
+            elif admitted and not self._chunked:
+                tick.set_metadata(kind="prefill")
+                for req in admitted:
+                    self._run_prefill(req, trace)
+            elif sch.running:
+                tick.set_metadata(kind="decode")
+                self._run_decode(trace)
+            else:
+                return False
+        self._tick += 1
+        return True
 
     def _split(self):
         self._key, sub = jax.random.split(self._key)
         return sub
+
+    def _launch(self, trace: str, program: str, fn, *args):
+        """``engine.launch``: a fresh sampling key and the jitted call,
+        which hands back the tick's tokens (still on the device) and the
+        donated pool.  A compilation inside it counts toward
+        ``serve_compiles_total{program}``."""
+        with phase(self.tracer, "engine.launch", trace,
+                   program=program) as launch:
+            c0 = compile_count()
+            tok, self.pool = fn(*args, self._split())
+            compiled = compile_count() - c0
+            if compiled:
+                self.metrics.inc("serve_compiles_total", compiled,
+                                 program=program)
+                launch.set_metadata(compiled=compiled)
+        return tok
 
     def _tick_obs(self, kind: str, n_tokens: int) -> None:
         """End-of-tick instrumentation: tick kind, tokens/tick, and the
@@ -394,8 +427,6 @@ class Engine:
         m.observe("serve_tokens_per_tick", n_tokens, kind=kind)
         for shard, used in enumerate(self.alloc.pages_in_use_by_shard()):
             m.set("serve_pages_in_use", used, shard=shard)
-            m.set("serve_pages_free", len(self.alloc._free[shard]),
-                  shard=shard)
 
     def _prefill_for(self, prompt_len: int):
         """The jitted prefill for this prompt: one fixed-capacity compile
@@ -455,84 +486,106 @@ class Engine:
         else:
             self._next_token[req.slot] = tok
 
-    def _run_prefill(self, req: Request) -> None:
-        ecfg, slot, plen = self.ecfg, req.slot, len(req.prompt)
-        step_len, prefill = self._prefill_for(plen)
-        tokens = np.full((1, step_len), ecfg.pad_id, np.int32)
-        tokens[0, :plen] = req.prompt
-        npg = -(-step_len // ecfg.page_size)
-        page_rows = self.alloc.block_table[slot:slot + 1, :npg]
-        tok, self.pool = prefill(
+    def _run_prefill(self, req: Request, trace: str = "") -> None:
+        tr = self.tracer
+        with phase(tr, "engine.plan", trace):
+            ecfg, slot, plen = self.ecfg, req.slot, len(req.prompt)
+            step_len, prefill = self._prefill_for(plen)
+            tokens = np.full((1, step_len), ecfg.pad_id, np.int32)
+            tokens[0, :plen] = req.prompt
+            npg = -(-step_len // ecfg.page_size)
+            page_rows = self.alloc.block_table[slot:slot + 1, :npg]
+        tok = self._launch(
+            trace, "prefill", prefill,
             self.params, tokens, np.array([plen - 1], np.int32), self.pool,
             np.ascontiguousarray(page_rows),
             np.array([slot], np.int32),
-            np.array([req.temperature], np.float32), self._split())
-        self.n_prefills += 1
-        self.n_prefill_tokens += plen
-        req.t_prefill_done = self.clock.now()
-        self._emit(req, int(tok[0]))
-        self._tick_obs("prefill", 1)
+            np.array([req.temperature], np.float32))
+        with phase(tr, "engine.sync", trace):
+            tok = np.asarray(tok)
+        with phase(tr, "engine.emit", trace):
+            self.n_prefills += 1
+            self.n_prefill_tokens += plen
+            req.t_prefill_done = self.clock.now()
+            self._emit(req, int(tok[0]))
+        with phase(tr, "engine.metrics", trace):
+            self._tick_obs("prefill", 1)
 
-    def _run_mixed(self, req: Request, start: int, n: int) -> None:
+    def _run_mixed(self, req: Request, start: int, n: int,
+                   trace: str = "") -> None:
         """One fused tick: decode every fully prefilled slot + consume
         ``n`` prompt tokens (positions ``start..start+n``) of ``req``."""
-        ecfg, slot = self.ecfg, req.slot
-        final = start + n >= len(req.prompt)
-        c_tokens = np.full((1, ecfg.prefill_chunk), ecfg.pad_id, np.int32)
-        c_tokens[0, :n] = req.prompt[start:start + n]
-        c_pages = np.ascontiguousarray(self.alloc.block_table[slot:slot + 1])
-        active = self.scheduler.decodable()         # slot -> request
-        for s in active:
-            self.alloc.ensure_page(s)
-        bt = self.alloc.block_table.copy()
-        lens = self.alloc.lengths.copy()
-        # mid-prefill slots must not decode: the view parks them on
-        # their null page at length 0 (the empty-slot convention)
-        for r_ in self.scheduler.prefilling:
-            bt[r_.slot, :] = self.alloc.null_page_of(r_.slot)
-            lens[r_.slot] = 0
-        temps = np.zeros((ecfg.n_slots,), np.float32)
-        for s, r_ in active.items():
-            temps[s] = r_.temperature
-        if final:
-            temps[slot] = req.temperature
-        tok, self.pool = self._mixed(
+        tr = self.tracer
+        with phase(tr, "engine.plan", trace):
+            ecfg, slot = self.ecfg, req.slot
+            final = start + n >= len(req.prompt)
+            c_tokens = np.full((1, ecfg.prefill_chunk), ecfg.pad_id,
+                               np.int32)
+            c_tokens[0, :n] = req.prompt[start:start + n]
+            c_pages = np.ascontiguousarray(
+                self.alloc.block_table[slot:slot + 1])
+            active = self.scheduler.decodable()     # slot -> request
+            for s in active:
+                self.alloc.ensure_page(s)
+            bt = self.alloc.block_table.copy()
+            lens = self.alloc.lengths.copy()
+            # mid-prefill slots must not decode: the view parks them on
+            # their null page at length 0 (the empty-slot convention)
+            for r_ in self.scheduler.prefilling:
+                bt[r_.slot, :] = self.alloc.null_page_of(r_.slot)
+                lens[r_.slot] = 0
+            temps = np.zeros((ecfg.n_slots,), np.float32)
+            for s, r_ in active.items():
+                temps[s] = r_.temperature
+            if final:
+                temps[slot] = req.temperature
+        tok = self._launch(
+            trace, "mixed", self._mixed,
             self.params, self.pool, self._next_token[:, None], bt, lens,
             c_tokens, c_pages, np.array([start], np.int32),
             np.array([n], np.int32),
             np.int32(self.alloc.null_page_of(slot)),
-            np.int32(slot), np.bool_(final), temps, self._split())
-        self.n_mixed_steps += 1
-        if n > 0:
-            self.n_prefills += 1          # this tick did prompt work
-            self.n_prefill_tokens += n
-        tok = np.asarray(tok)
-        for s, r_ in active.items():
-            self.alloc.advance(s)
-            self._emit(r_, int(tok[s]))
-        done = self.scheduler.chunk_done(req, n)
-        if done:
-            req.t_prefill_done = self.clock.now()
-            self._emit(req, int(tok[slot]))
-        self._tick_obs("mixed", len(active) + (1 if done else 0))
+            np.int32(slot), np.bool_(final), temps)
+        with phase(tr, "engine.sync", trace):
+            tok = np.asarray(tok)
+        with phase(tr, "engine.emit", trace):
+            self.n_mixed_steps += 1
+            if n > 0:
+                self.n_prefills += 1          # this tick did prompt work
+                self.n_prefill_tokens += n
+            for s, r_ in active.items():
+                self.alloc.advance(s)
+                self._emit(r_, int(tok[s]))
+            done = self.scheduler.chunk_done(req, n)
+            if done:
+                req.t_prefill_done = self.clock.now()
+                self._emit(req, int(tok[slot]))
+        with phase(tr, "engine.metrics", trace):
+            self._tick_obs("mixed", len(active) + (1 if done else 0))
 
-    def _run_decode(self) -> None:
-        active = dict(self.scheduler.running)       # slot -> request
-        for slot in active:
-            self.alloc.ensure_page(slot)
-        temps = np.zeros((self.ecfg.n_slots,), np.float32)
-        for slot, req in active.items():
-            temps[slot] = req.temperature
-        tok, self.pool = self._decode(
-            self.params, self.pool, self._next_token[:, None],
-            self.alloc.block_table.copy(), self.alloc.lengths.copy(),
-            temps, self._split())
-        self.n_decode_steps += 1
-        tok = np.asarray(tok)
-        for slot, req in active.items():
-            self.alloc.advance(slot)
-            self._emit(req, int(tok[slot]))
-        self._tick_obs("decode", len(active))
+    def _run_decode(self, trace: str = "") -> None:
+        tr = self.tracer
+        with phase(tr, "engine.plan", trace):
+            active = dict(self.scheduler.running)   # slot -> request
+            for slot in active:
+                self.alloc.ensure_page(slot)
+            temps = np.zeros((self.ecfg.n_slots,), np.float32)
+            for slot, req in active.items():
+                temps[slot] = req.temperature
+            bt = self.alloc.block_table.copy()
+            lens = self.alloc.lengths.copy()
+        tok = self._launch(trace, "decode", self._decode, self.params,
+                           self.pool, self._next_token[:, None], bt, lens,
+                           temps)
+        with phase(tr, "engine.sync", trace):
+            tok = np.asarray(tok)
+        with phase(tr, "engine.emit", trace):
+            self.n_decode_steps += 1
+            for slot, req in active.items():
+                self.alloc.advance(slot)
+                self._emit(req, int(tok[slot]))
+        with phase(tr, "engine.metrics", trace):
+            self._tick_obs("decode", len(active))
 
     # -- stats --------------------------------------------------------------
     def stats(self) -> dict:

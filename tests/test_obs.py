@@ -10,6 +10,11 @@ Contract under test (mirrors ROADMAP "Observability contract"):
     finished request's TTFT spans (router_hold + queue_wait + prefill
     + first_decode) telescope to its stamped ``ttft_e2e`` EXACTLY,
     under the wall clock and under a virtual tick clock;
+  - engine ticks: each ``Engine.step`` tick is one ``engine.step`` span
+    whose six phase children nest inside it in order, as tracer spans
+    and as ``jax.profiler`` annotations, without changing a token;
+    ``serve_compiles_total{program}`` counts compilations inside a
+    launch (warm-up ticks only);
   - clock injection: Router inherits the engines' clock, so SLO-slack
     dispatch ordering is deterministic under sim time (the fleet-bench
     clock-split fix);
@@ -28,9 +33,9 @@ from repro.configs.base import ModelConfig
 from repro.core import (Autoscaler, FluxMiniCluster, MiniClusterSpec,
                         NetModel, ResourceGraph, SimClock)
 from repro.models.model import Model
-from repro.obs import (MetricsRegistry, SimTime, TickClock, Tracer,
-                       WallClock, provenance, spans_from_handle,
-                       to_chrome_trace, ttft_breakdown)
+from repro.obs import (ENGINE_PHASES, MetricsRegistry, SimTime, TickClock,
+                       Tracer, WallClock, compile_count, phase, provenance,
+                       spans_from_handle, to_chrome_trace, ttft_breakdown)
 from repro.obs.trace import TTFT_SPANS
 from repro.serve import Engine, EngineConfig, Router
 
@@ -236,6 +241,131 @@ def test_traced_engine_reconstructs_ttft_exactly_tick(params):
         got = ttft_breakdown(tracer.spans_for(f"req-{r.rid}"))
         assert got["sum_s"] == r.ttft_e2e
         assert float(got["sum_s"]).is_integer()    # pure tick axis
+
+
+# ---------------------------------------------------------------------------
+# Engine tick spans and the compile counter
+# ---------------------------------------------------------------------------
+
+
+def _serve(params, tracer=None, ecfg=ECFG):
+    """Two requests, the second arriving mid-decode: mixed ticks while
+    a prompt is chunked in, decode ticks otherwise."""
+    eng = Engine(TINY, ecfg, params=params, tracer=tracer)
+    a = eng.submit([3, 1, 4, 1, 5], max_new_tokens=5)
+    for _ in range(4):
+        eng.step()
+    b = eng.submit([2, 7, 1, 8, 2, 8], max_new_tokens=4)
+    eng.run()
+    return eng, [a.tokens, b.tokens]
+
+
+def _ticks(tracer):
+    """Each ``tick-<n>`` trace's ``engine.step`` span and its other
+    spans, in start order."""
+    out = {}
+    for sp in sorted(tracer.spans, key=lambda s: s.t_start):
+        if sp.trace.startswith("tick-"):
+            out.setdefault(sp.trace, []).append(sp)
+    return out
+
+
+def test_phase_is_a_tracer_span_and_takes_late_attributes():
+    clock = TickClock()
+    tr = Tracer(clock)
+    with phase(tr, "engine.step", "tick-0", tick=0) as p:
+        clock.advance(2.0)
+        p.set_metadata(kind="decode")
+    with phase(None, "engine.step", "tick-1", tick=1) as q:
+        q.set_metadata(kind="mixed")         # no tracer: annotation only
+    [sp] = tr.spans
+    assert (sp.name, sp.trace, sp.duration) == ("engine.step", "tick-0", 2.0)
+    assert sp.attrs == {"tick": 0, "kind": "decode"}
+    assert isinstance(q, jax.profiler.TraceAnnotation)
+    assert not tr.open_spans()
+
+
+def test_engine_tokens_identical_with_and_without_tracer(params):
+    _, plain = _serve(params)
+    _, traced = _serve(params, Tracer(WallClock()))
+    assert plain == traced and all(plain)
+
+
+@pytest.mark.parametrize("chunk", [4, 0], ids=["chunked", "one_shot"])
+def test_engine_step_spans_nest_six_phases_in_order(params, chunk):
+    tracer = Tracer(WallClock())
+    ecfg = EngineConfig(n_slots=2, page_size=4, max_seq_len=32,
+                        max_prompt_len=8, prefill_chunk=chunk)
+    eng, _ = _serve(params, tracer, ecfg)
+    ticks = _ticks(tracer)
+    assert len(ticks) == sum(eng.metrics.value("serve_ticks_total", kind=k)
+                             for k in ("prefill", "mixed", "decode"))
+    kinds = set()
+    for n, (step, *kids) in enumerate(ticks[t] for t in sorted(
+            ticks, key=lambda t: int(t.split("-")[1]))):
+        assert step.name == "engine.step" and step.trace == f"tick-{n}"
+        assert step.attrs["tick"] == n
+        kinds.add(step.attrs["kind"])
+        # one request a one-shot tick here: six children either way
+        assert tuple(k.name for k in kids) == ENGINE_PHASES
+        for k in kids:
+            assert step.t_start <= k.t_start <= k.t_end <= step.t_end
+        for a, b in zip(kids, kids[1:]):
+            assert a.t_end <= b.t_start
+        assert sum(k.duration for k in kids) <= step.duration
+        [launch] = [k for k in kids if k.name == "engine.launch"]
+        assert launch.attrs["program"] == step.attrs["kind"]
+    assert kinds == ({"mixed", "decode"} if chunk else {"prefill", "decode"})
+
+
+def test_engine_counts_compiles_on_warm_up_ticks_only(params):
+    tracer = Tracer(WallClock())
+    eng = Engine(TINY, ECFG, params=params, tracer=tracer)
+
+    def compiles():
+        return {p: eng.metrics.value("serve_compiles_total", program=p)
+                for p in ("mixed", "decode")}
+
+    eng.submit([3, 1, 4, 1, 5], max_new_tokens=6)
+    c0 = compile_count()
+    eng.step()                                   # first mixed tick
+    assert compiles()["mixed"] >= 1 and compiles()["decode"] == 0
+    while eng.n_decode_steps == 0:
+        eng.step()
+    warm = compiles()
+    assert warm["decode"] >= 1
+    assert compile_count() - c0 >= sum(warm.values())
+    launches = [s for s in tracer.spans if s.name == "engine.launch"]
+    assert launches[0].attrs["compiled"] == warm["mixed"]
+    # the same shapes again: decode ticks, a new prompt's mixed ticks
+    eng.submit([2, 7, 1, 8, 2, 8], max_new_tokens=4)
+    eng.run()
+    assert eng.n_mixed_steps > 2 and eng.n_decode_steps > 2
+    assert compiles() == warm
+    assert sum("compiled" in s.attrs for s in tracer.spans
+               if s.name == "engine.launch") == 2
+
+
+def test_engine_spans_reach_the_profiler_trace(params, tmp_path):
+    from jax.profiler import ProfileData
+    eng = Engine(TINY, ECFG, params=params)
+    eng.submit([3, 1, 4, 1, 5], max_new_tokens=4)
+    eng.step()                                     # compile outside
+    jax.profiler.start_trace(str(tmp_path))
+    eng.run()
+    jax.profiler.stop_trace()
+    [path] = list(tmp_path.rglob("*.xplane.pb"))
+    got = [(ev.name, dict(ev.stats))
+           for plane in ProfileData.from_file(str(path)).planes
+           if plane.name.startswith("/host:")
+           for line in plane.lines for ev in line.events
+           if ev.name.startswith("engine.")]
+    steps = [st for name, st in got if name == "engine.step"]
+    assert len(steps) == eng.n_mixed_steps + eng.n_decode_steps - 1
+    assert {st["kind"] for st in steps} == {"mixed", "decode"}
+    assert {name for name, _ in got} == {"engine.step", *ENGINE_PHASES}
+    assert {st["program"] for name, st in got
+            if name == "engine.launch"} == {"mixed", "decode"}
 
 
 # ---------------------------------------------------------------------------
