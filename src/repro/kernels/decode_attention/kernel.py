@@ -99,15 +99,110 @@ def decode_attention_kernel(q, k, v, cache_len, *, scale=None,
 
 
 # --------------------------------------------------------------------------
-# Paged variant: the KV sweep walks the slot's block table instead of a
-# contiguous cache.  Scalar-prefetched block tables let the BlockSpec
-# index maps DMA exactly the pages the slot owns — decode reads scale
-# with the FILLED pages, and no gather materializes the cache in HBM.
+# Paged variant: one grid step per slot walks the slot's block table in
+# blocks of several pages and every kv head.  The pool stays in HBM; each
+# filled page of a block, a contiguous (Hkv, page, D) tile of all heads,
+# is gathered by its own DMA into a double-buffered VMEM block, so one
+# copy serves all g * Hkv query heads and the copy of the next block
+# (the next slot's first, after a slot's last) overlaps this block's
+# compute.  A slot walks ceil(length / block) blocks, and pages past the
+# fill are neither copied nor computed: decode reads scale with the
+# FILLED pages, and no gather materializes the cache in HBM.
+#
+# Mosaic slices an HBM pool for a manual DMA only when D is a multiple
+# of 128 lanes; other head dims walk one page of one kv head per grid
+# step, delivered by the BlockSpec pipeline (``_paged_page_kernel``).
 # --------------------------------------------------------------------------
 
+BLOCK_TOKENS = 256                  # keys per block of the paged walk
+BLOCK_BYTES = 512 * 1024            # K + V bytes per block
 
-def _paged_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                  m_scr, l_scr, acc_scr, *, scale, page, g):
+
+def pages_per_block(page, hkv, d, itemsize, maxp):
+    """Pages the paged decode kernel gathers per block: about
+    BLOCK_TOKENS keys, at most BLOCK_BYTES of K and V, at most a slot."""
+    per_page = 2 * hkv * page * d * itemsize
+    return max(1, min(maxp, BLOCK_TOKENS // page, BLOCK_BYTES // per_page))
+
+
+def _paged_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
+                  k_buf, v_buf, sems, buf_scr, m_scr, l_scr, acc_scr,
+                  *, scale, page, ppb, hkv, g):
+    bi = pl.program_id(0)
+    nb_slots = pl.num_programs(0)
+    bk = ppb * page                                     # keys per block
+    length = len_ref[bi]
+    nblk = pl.cdiv(length, bk)
+
+    def dmas(slot, blk, buf, start):
+        """Start (or wait for) the K and V copies of the block's filled
+        pages: pages past the slot's fill are never copied."""
+        n = jnp.clip(pl.cdiv(len_ref[slot], page) - blk * ppb, 0, ppb)
+
+        def one(j, carry):
+            src = bt_ref[slot, blk * ppb + j]
+            dst = pl.ds(j * page, page)
+            for hbm, vbuf, s in ((k_hbm, k_buf, 0), (v_hbm, v_buf, 1)):
+                cp = pltpu.make_async_copy(hbm.at[src], vbuf.at[buf, :, dst],
+                                           sems.at[s, buf])
+                cp.start() if start else cp.wait()
+            return carry
+
+        jax.lax.fori_loop(0, n, one, 0)
+
+    @pl.when(bi == 0)
+    def _first_slot():
+        buf_scr[0] = 0
+        v_buf[...] = jnp.zeros_like(v_buf)   # unfilled rows meet p == 0
+
+    # the previous slot prefetched this one's first block iff it had work
+    prefetched = (bi > 0) & (len_ref[jnp.maximum(bi - 1, 0)] > 0)
+    buf0 = buf_scr[0]
+
+    @pl.when(jnp.logical_not(prefetched))
+    def _own_first_block():
+        dmas(bi, 0, buf0, start=True)
+
+    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    def body(blk, carry):
+        buf = (buf0 + blk) % 2
+        more = blk + 1 < nblk
+
+        @pl.when(more | (bi + 1 < nb_slots))
+        def _prefetch():                 # this slot's next block, or the
+            nslot = jnp.where(more, bi, bi + 1)     # next slot's first
+            dmas(nslot, jnp.where(more, blk + 1, 0), 1 - buf, start=True)
+
+        dmas(bi, blk, buf, start=False)
+        kpos = blk * bk + jax.lax.broadcasted_iota(jnp.int32, (g, bk), 1)
+        for h in range(hkv):
+            q = q_ref[0, h].astype(jnp.float32)            # g x d
+            k = k_buf[buf, h].astype(jnp.float32)          # bk x d
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            s = jnp.where(kpos < length, s * scale, NEG_INF)   # g x bk
+            m_prev = m_scr[h]
+            m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_scr[h] = l_scr[h] * alpha + p.sum(axis=1, keepdims=True)
+            v = v_buf[buf, h].astype(jnp.float32)
+            acc_scr[h] = acc_scr[h] * alpha + jax.lax.dot(
+                p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+            m_scr[h] = m_new
+        return carry
+
+    jax.lax.fori_loop(0, nblk, body, 0)
+    buf_scr[0] = (buf0 + nblk) % 2
+    l = jnp.maximum(l_scr[...], 1e-30)
+    o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
+
+
+def _paged_page_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
+                       m_scr, l_scr, acc_scr, *, scale, page, g):
     bi = pl.program_id(0)
     pi = pl.program_id(2)
     np_ = pl.num_programs(2)
@@ -144,22 +239,13 @@ def _paged_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
-def paged_decode_attention_kernel(q, k_pages, v_pages, block_table,
-                                  lengths, *, scale=None, interpret=False):
-    """q: (B, 1, H, D); k_pages, v_pages: (P, Hkv, page, D) — one
-    page of one kv head is a contiguous (page, D) tile, the kernel's
-    block; block_table: (B, pages_per_slot) int32; lengths: (B,) int32."""
-    b, one, h, d = q.shape
-    _, hkv, page, _ = k_pages.shape
-    maxp = block_table.shape[1]
-    g = h // hkv
-    scale = scale or d ** -0.5
-    qg = q[:, 0].reshape(b, hkv, g, d)                  # B Hkv g D
-
+def _paged_decode_by_page(qg, k_pages, v_pages, bt, lengths, scale,
+                          interpret):
+    b, hkv, g, d = qg.shape
+    page = k_pages.shape[2]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,                          # block_table, lengths
-        grid=(b, hkv, maxp),
+        grid=(b, hkv, bt.shape[1]),
         in_specs=[
             pl.BlockSpec((1, 1, g, d),
                          lambda b_, hk, pi, bt, ln: (b_, hk, 0, 0)),
@@ -174,13 +260,63 @@ def paged_decode_attention_kernel(q, k_pages, v_pages, block_table,
                         pltpu.VMEM((g, 1), jnp.float32),
                         pltpu.VMEM((g, d), jnp.float32)],
     )
+    return pl.pallas_call(
+        functools.partial(_paged_page_kernel, scale=scale, page=page, g=g),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(qg.shape, qg.dtype),
+        interpret=interpret,
+    )(bt, lengths, qg, k_pages, v_pages)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def paged_decode_attention_kernel(q, k_pages, v_pages, block_table,
+                                  lengths, *, scale=None, interpret=False):
+    """q: (B, 1, H, D); k_pages, v_pages: (P, Hkv, page, D) — one
+    page of all kv heads is a contiguous (Hkv, page, D) tile, one DMA;
+    block_table: (B, pages_per_slot) int32; lengths: (B,) int32."""
+    b, one, h, d = q.shape
+    _, hkv, page, _ = k_pages.shape
+    maxp = block_table.shape[1]
+    g = h // hkv
+    scale = scale or d ** -0.5
+    qg = q[:, 0].reshape(b, hkv, g, d)                  # B Hkv g D
+    bt = block_table.astype(jnp.int32)
+    lengths = lengths.astype(jnp.int32)
+    if d % 128:
+        out = _paged_decode_by_page(qg, k_pages, v_pages, bt, lengths,
+                                    scale, interpret)
+        return out.reshape(b, 1, h, d)
+    ppb = pages_per_block(page, hkv, d, k_pages.dtype.itemsize, maxp)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,                          # block_table, lengths
+        grid=(b,),
+        in_specs=[
+            pl.BlockSpec((1, hkv, g, d), lambda b_, bt, ln: (b_, 0, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((1, hkv, g, d),
+                               lambda b_, bt, ln: (b_, 0, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, hkv, ppb * page, d), k_pages.dtype),
+            pltpu.VMEM((2, hkv, ppb * page, d), v_pages.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),            # (K/V, buffer)
+            pltpu.SMEM((1,), jnp.int32),                # slot's 1st buffer
+            pltpu.VMEM((hkv, g, 1), jnp.float32),
+            pltpu.VMEM((hkv, g, 1), jnp.float32),
+            pltpu.VMEM((hkv, g, d), jnp.float32)],
+    )
     out = pl.pallas_call(
-        functools.partial(_paged_kernel, scale=scale, page=page, g=g),
+        functools.partial(_paged_kernel, scale=scale, page=page, ppb=ppb,
+                          hkv=hkv, g=g),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
+        # slots run in order: each prefetches the next one's first block
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(block_table.astype(jnp.int32), lengths.astype(jnp.int32),
-      qg, k_pages, v_pages)
+    )(bt, lengths, qg, k_pages, v_pages)
     return out.reshape(b, 1, h, d)
 
 

@@ -6,6 +6,7 @@ import numpy as np
 import pytest
 
 from repro.kernels import ops
+from repro.kernels.decode_attention import kernel as dec_kernel
 from repro.kernels.decode_attention import ref as dec_ref
 from repro.kernels.flash_attention import ref as fa_ref
 
@@ -127,14 +128,51 @@ def test_paged_decode_ref_matches_gathered_oracle(h, hkv):
                                       np.asarray(ref))
 
 
-@pytest.mark.parametrize("h,hkv", [(4, 2), (8, 8)])
-def test_paged_decode_pallas_matches_ref(h, hkv):
-    b, page, maxp, d = 2, 16, 3, 32
-    q, kp, vp, bt, fills = _mk_paged(b, 8, page, maxp, hkv, h, d,
-                                     fills=[40, 17])
+def _edge_fills(page, hkv, d, maxp):
+    """Fills at the paged decode kernel's edges: one key, one page less
+    one, exactly one block, one block plus one, a full slot."""
+    bk = page * dec_kernel.pages_per_block(page, hkv, d, 4, maxp)
+    return [1, page - 1, bk, bk + 1, maxp * page]
+
+
+@pytest.mark.parametrize("h,hkv,d", [
+    pytest.param(4, 2, 32, id="4-2"),       # d off the 128 lanes: by page
+    pytest.param(8, 8, 32, id="8-8"),
+    *[pytest.param(g * hkv, hkv, 128, id=f"g{g}-hkv{hkv}")
+      for g in (1, 8, 16) for hkv in (1, 2, 4)],
+])
+def test_paged_decode_pallas_matches_ref(h, hkv, d):
+    """Every edge fill in one batch, slots more than a block long, and a
+    last slot of length 1 on the null page (a slot mid-prefill in the
+    mixed step)."""
+    page = 16
+    maxp = 2 * dec_kernel.pages_per_block(page, hkv, d, 4, 1 << 20) + 1
+    fills = _edge_fills(page, hkv, d, maxp) + [1]
+    n_pages = sum(-(-f // page) for f in fills) + 1
+    q, kp, vp, bt, fills = _mk_paged(len(fills), n_pages, page, maxp, hkv,
+                                     h, d, fills=fills)
+    bt = bt.at[-1].set(0)
     ref = dec_ref.paged_decode_ref(q, kp, vp, bt, fills)
     out = ops.paged_decode_attention(q, kp, vp, bt, fills,
                                      impl="interpret")
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)
+
+
+def test_paged_decode_block_walk_under_tpu_interpreter():
+    """The TPU interpreter fills scratch with NaN and lands each DMA only
+    at its wait: the block walk reads no row it did not copy and no
+    buffer before its copy has landed."""
+    from jax.experimental.pallas import tpu as pltpu
+    page, hkv, d, g = 16, 2, 128, 8
+    maxp = 2 * dec_kernel.pages_per_block(page, hkv, d, 4, 1 << 20) + 1
+    fills = _edge_fills(page, hkv, d, maxp)
+    n_pages = sum(-(-f // page) for f in fills) + 1
+    q, kp, vp, bt, fills = _mk_paged(len(fills), n_pages, page, maxp, hkv,
+                                     g * hkv, d, fills=fills)
+    ref = dec_ref.paged_decode_ref(q, kp, vp, bt, fills)
+    out = dec_kernel.paged_decode_attention_kernel(
+        q, kp, vp, bt, fills, interpret=pltpu.InterpretParams())
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
 

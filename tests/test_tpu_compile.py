@@ -1,5 +1,6 @@
 """Compile rehearsals: the main-path Pallas kernels at yi-6b's published
-widths, compiled for a described (not attached) TPU v5e chip.
+widths (the paged decode kernel also at chatglm3-6b's), compiled for a
+described (not attached) TPU v5e chip.
 
 Interpret mode cannot see what the chip's compiler refuses — block
 shapes off the (8, 128) tiling, VMEM overruns — so each test lowers the
@@ -55,16 +56,26 @@ def _compile(chip, fn, *shapes):
     return compiled
 
 
-@pytest.mark.parametrize("page", [8, 16])
-def test_paged_decode_compiles_for_v5e(chip, page):
-    maxp = SEQ // page
-    n_pages = SLOTS * maxp + 1
+@pytest.mark.parametrize("arch,slots,seq,page", [
+    pytest.param("yi-6b", SLOTS, SEQ, 8, id="8"),
+    pytest.param("yi-6b", SLOTS, SEQ, 16, id="16"),
+    # the benched serving shapes: the block walk's VMEM at both widths
+    pytest.param("yi-6b", 12, 2048, 16, id="yi-6b-12x2048"),
+    pytest.param("chatglm3-6b", 24, 2048, 16, id="chatglm3-6b-24x2048"),
+    # head dim 64: the one-page walk
+    pytest.param("granite-moe-1b-a400m", 8, 2048, 16, id="granite-d64"),
+])
+def test_paged_decode_compiles_for_v5e(chip, arch, slots, seq, page):
+    cfg = registry.get(arch)
+    h, kv, d = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    maxp = seq // page
+    n_pages = slots * maxp + 1
     bf, i32 = jnp.bfloat16, jnp.int32
     _compile(chip, functools.partial(ops.paged_decode_attention,
                                      impl="pallas"),
-             ((SLOTS, 1, H, D), bf), ((n_pages, KV, page, D), bf),
-             ((n_pages, KV, page, D), bf), ((SLOTS, maxp), i32),
-             ((SLOTS,), i32))
+             ((slots, 1, h, d), bf), ((n_pages, kv, page, d), bf),
+             ((n_pages, kv, page, d), bf), ((slots, maxp), i32),
+             ((slots,), i32))
 
 
 @pytest.mark.parametrize("page", [8, 16])
