@@ -4,7 +4,7 @@
     python3 bench/calibrate.py --workload yi6b.prefill \\
         --seeds 1,2,3 --control-seeds 1,2 --seconds 20
 
-One process: for each seed the cell's engine is built with that seed's
+One process: for each seed the cell's server is built with that seed's
 weights, warmed up, and offered the cell's traffic for ``--seconds``
 (then drained), exactly as a run does.  The finished requests sampled
 as a run samples them are compared with the plain reference: the
@@ -47,20 +47,21 @@ def main() -> int:
     controls = {int(s) for s in args.control_seeds.split(",") if s}
     for seed in (int(s) for s in args.seeds.split(",")):
         t = time.perf_counter()
-        weights = check.make_weights(cfg, seed)
-        eng = harness.build_engine(cfg, weights)
-        harness.warm_up(eng)
+        server = harness.build_server(cell, seed)
+        harness.warm_up(server, cell.mix)
         arrivals = traffic.schedule(cell.mix, seed, args.seconds,
                                     cfg["spec"]["serve"]["n_slots"],
                                     cfg["model"]["vocab_size"])
-        rec = harness.serve_window(eng, arrivals, args.seconds,
+        rec = harness.serve_window(server, arrivals, args.seconds,
                                    cell.mix["drain_s"])
         picked = check.sample(rec["flights"], seed,
                               cell.mix["check_requests"])
-        del eng, rec
+        del server, rec
         gc.collect()
+        weights = check.make_weights(cfg, seed)
         row = {"seed": seed, "checked": len(picked),
                "served_tokens": sum(len(f.tokens) for f in picked),
+               "adopted_tokens": sum(f.adopted for f in picked),
                "program_gap": check.served_gap(cfg, weights, picked)}
         if seed in controls:
             row["control_gap"] = check.control_gaps(cfg, weights, picked)

@@ -32,7 +32,24 @@ MIN_BUCKET = 128
 
 
 def reference(config: dict):
+    """The configuration's plain reference, ``references/<name>.py``.
+
+    Besides ``init_weights`` and ``logits`` it declares the model kind
+    it computes, which the harness holds the program's configuration
+    to: ``FIELDS``, the keys of the configuration file's ``model`` group
+    that must equal the program configuration's attributes of the same
+    name (a group given as an object is compared key by key), and
+    ``KIND``, program attributes and the values it computes them for.
+    ``COUNTS`` names the module under ``bench/`` with that kind's
+    operation and byte counts; ``counts`` when it names none."""
     return importlib.import_module(f"bench.references.{config['reference']}")
+
+
+def counts(config: dict):
+    """The operation and byte counts of the configuration's model kind,
+    as its reference names them."""
+    name = getattr(reference(config), "COUNTS", "counts")
+    return importlib.import_module(f"bench.{name}")
 
 
 def bucket(n: int) -> int:
@@ -43,7 +60,10 @@ def bucket(n: int) -> int:
 
 def sample(flights: Sequence, seed: int, k: int) -> List:
     """Up to ``k`` finished flights: the one that served most tokens,
-    and the rest drawn from the seed."""
+    and the rest drawn from the seed.  Where some flights started from
+    a prefix copied out of a prefix cache and the draw holds none, the
+    last one drawn gives way to one of those, drawn from the seed too,
+    so that a wrongly copied prefix shows."""
     done = [f for f in flights if f.finished]
     if not done:
         return []
@@ -51,7 +71,11 @@ def sample(flights: Sequence, seed: int, k: int) -> List:
     rest = [f for f in done if f is not longest]
     rng = traffic.rng_for(seed + 1)
     pick = rng.choice(len(rest), size=min(k - 1, len(rest)), replace=False)
-    return [longest] + [rest[i] for i in sorted(pick)]
+    out = [longest] + [rest[i] for i in sorted(pick)]
+    adopted = [f for f in rest if f.adopted]
+    if adopted and len(out) > 1 and not any(f.adopted for f in out):
+        out[-1] = adopted[rng.integers(len(adopted))]
+    return out
 
 
 def _sequence(f):
@@ -88,11 +112,15 @@ def _gap_fn(ref, model_key, control: bool):
     return fn
 
 
-def make_weights(config: dict, seed: int):
-    """The seed's weights, on the default device, in one jitted call."""
+def make_weights(config: dict, seed: int, device=None):
+    """The seed's weights, on ``device`` (the default device when None),
+    in one jitted call."""
     m = config["model"]
     ref = reference(config)
-    return jax.jit(functools.partial(ref.init_weights, m))(weight_key(seed))
+    out = None if device is None else jax.sharding.SingleDeviceSharding(
+        device)
+    return jax.jit(functools.partial(ref.init_weights, m),
+                   out_shardings=out)(weight_key(seed))
 
 
 def weight_key(seed: int):

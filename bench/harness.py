@@ -1,14 +1,25 @@
 """One run of one cell: set-up, the measured window, metrics, the check.
 
-The window drives the engine through its public API only:
-``Engine.submit`` for each request when it falls due, and
-``Engine.step`` while any submitted request is unfinished.  After each
-step the harness polls every request in flight and stamps the host
-time at which each new token became visible; time to first token
-counts from the request's due time, so a late generator or a stalled
-loop shows in it.  Every step, submit and wait for an arrival is
-wrapped in a ``jax.profiler.TraceAnnotation`` so that a traced run can
-attribute the device's idle gaps to what the host was doing.
+A cell's server is what its files describe.  Every engine holds the
+whole model on one chip, as its configuration file's ``spec`` builds
+it; a cell on one chip serves from one ``Engine``, a cell on N chips
+from N such replicas, each on its own chip with its own copy of the
+seed's weights, behind one ``repro.serve.Router`` (as ``launch.serve``
+builds a fleet).  The configuration's reference module
+(``references/<reference>.py``) says which model kind it computes
+(``FIELDS``, ``KIND``) and names the operation counts of that kind
+(``COUNTS``, else ``counts.py``); the harness holds the program's
+configuration to it and hands the counts to the metric readers.
+
+The window drives the server through its public API only: ``submit``
+for each request when it falls due, and ``step`` while any submitted
+request is unfinished.  After each step the harness polls every
+request in flight and stamps the host time at which each new token
+became visible; time to first token counts from the request's due
+time, so a late generator or a stalled loop shows in it.  Every step,
+submit and wait for an arrival is wrapped in a
+``jax.profiler.TraceAnnotation`` so that a traced run can attribute the
+device's idle gaps to what the host was doing.
 """
 from __future__ import annotations
 
@@ -38,7 +49,7 @@ WARM_PROMPT = 16              # warm-up request: one chunk, two tokens
 @dataclass
 class Cell:
     name: str
-    chips: int
+    chips: int                # one engine replica on each
     config: dict              # configs/<config>.json
     mix: dict                 # traffic/<traffic>.json
     limits: dict              # checks/<cell>.json
@@ -72,47 +83,79 @@ def load_cell(name: str, manifest: Optional[dict] = None) -> Cell:
                 end_to_end=e2e, per_layer=per)
 
 
-# -- engine ------------------------------------------------------------------
+# -- the server -------------------------------------------------------------
 
-MODEL_FIELDS = ("d_model", "n_layers", "n_heads", "n_kv_heads", "head_dim",
-                "d_ff", "vocab_size", "rope_theta", "rope_fraction",
-                "qkv_bias", "norm_eps", "tie_embeddings")
-MODEL_KIND = {"family": "dense", "block_pattern": ("attn",), "moe": None,
-              "norm_type": "rmsnorm", "mlp_type": "swiglu",
-              "pos_type": "rope"}
+def _same(program, bench) -> bool:
+    """A group of the configuration file (a dict) matches the program's
+    attribute of that name key by key; anything else compares equal."""
+    if isinstance(bench, dict):
+        return program is not None and all(
+            _same(getattr(program, k), v) for k, v in bench.items())
+    return program == bench
 
 
-def check_model(cfg, m: dict) -> None:
+def check_model(cfg, m: dict, ref) -> None:
     """The program's configuration must be the model the reference
-    computes: same sizes, same kind of block."""
+    ``ref`` computes: the sizes it reads from the configuration file's
+    ``model`` group (``ref.FIELDS``) and the kind of model it is
+    (``ref.KIND``, program attributes and their values)."""
     bad = [f"{k}: program {getattr(cfg, k)!r}, bench {m[k]!r}"
-           for k in MODEL_FIELDS if getattr(cfg, k) != m[k]]
+           for k in ref.FIELDS if not _same(getattr(cfg, k), m[k])]
     bad += [f"{k}: program {getattr(cfg, k)!r}, reference wants {v!r}"
-            for k, v in MODEL_KIND.items() if getattr(cfg, k) != v]
+            for k, v in ref.KIND.items() if not _same(getattr(cfg, k), v)]
     if bad:
         raise SystemExit("program config differs from the benchmark's:\n  "
                          + "\n  ".join(bad))
 
 
-def build_engine(config: dict, weights):
+def build_engine(config: dict, weights, device):
     """The engine the way ``launch.serve`` builds it from a serve spec,
-    holding the benchmark's weights."""
+    holding the benchmark's weights, on ``device`` alone."""
     from repro.configs import registry
+    from repro.dist.sharding import make_mesh
     from repro.serve import Engine
     from repro.spec import WorkloadSpec
     spec = WorkloadSpec.from_dict(config["spec"]).validate()
     cfg = registry.get(spec.arch)
-    check_model(cfg, config["model"])
+    check_model(cfg, config["model"], check.reference(config))
     return Engine(cfg, spec.engine_config(), strategy=spec.resolved_strategy,
+                  mesh=make_mesh((1, 1), ("data", "model"), devices=[device]),
                   params=weights)
 
 
-def warm_up(eng) -> None:
-    """Compile (or load from the cache) the two programs the window
-    runs: a mixed tick, then a decode tick."""
-    req = eng.submit([1] * WARM_PROMPT, max_new_tokens=2)
-    while not req.finished:
-        eng.step()
+def build_server(cell: Cell, seed: int):
+    """The cell's server with the seed's weights: one engine on each of
+    the cell's chips, behind a Router where there are several."""
+    import jax
+    cfg = cell.config
+    reps = [build_engine(cfg, check.make_weights(cfg, seed, d), d)
+            for d in jax.devices()[:cell.chips]]
+    if len(reps) == 1:
+        return reps[0]
+    from repro.serve import Router
+    return Router(reps)
+
+
+def engines(server) -> list:
+    return list(getattr(server, "engines", [server]))
+
+
+def warm_up(server, mix: Optional[dict] = None) -> None:
+    """Compile (or load from the cache) the programs the window runs, on
+    every replica: a mixed tick, then a decode tick.  Behind a prefix
+    cache, session traffic also copies a cached system prompt's pages
+    into a slot: a second wave of requests adopts what the first one's
+    prompts registered, at the pages a system prompt fills."""
+    n = len(engines(server))
+    prompts = [[1] * WARM_PROMPT]
+    sessions = (mix or {}).get("sessions")
+    if sessions and getattr(server, "prefix_cache", None) is not None:
+        system = [3] * sessions["system_prompt_tokens"]
+        prompts = [system + [1] * WARM_PROMPT, system + [2] * WARM_PROMPT]
+    for prompt in prompts:
+        reqs = [server.submit(prompt, max_new_tokens=2) for _ in range(n)]
+        while not all(r.finished for r in reqs):
+            server.step()
 
 
 # -- the window ----------------------------------------------------------------
@@ -124,11 +167,13 @@ class Flight:
     due: float
     prompt: List[int]
     max_new: int
+    tenant: str = "default"
     req: object = None
     t_submit: Optional[float] = None
     failed: bool = False
     t_tokens: List[float] = field(default_factory=list)
     progress: int = 0                  # prompt tokens seen in the pages
+    adopted: int = 0                   # of them copied from a prefix cache
     t_admit: Optional[float] = None
 
     @property
@@ -166,50 +211,81 @@ class Tracer:
         shutil.rmtree(self.dir, ignore_errors=True)
 
 
-def _tick_counts(eng) -> dict:
+def _tick_counts(server) -> dict:
+    """The registry's tokens-per-tick counts, summed over the replicas
+    (a fleet's registry labels each engine's series ``source``)."""
+    if hasattr(server, "metrics_view"):
+        reg = server.metrics_view()
+        labels = [{"source": f"engine{i}"}
+                  for i in range(len(server.engines))]
+    else:
+        reg, labels = server.metrics, [{}]
     out = {"count": 0.0, "sum": 0.0}
     for kind in ("mixed", "decode"):
-        h = eng.metrics.histogram("serve_tokens_per_tick", kind=kind) or {}
-        out["count"] += h.get("count", 0)
-        out["sum"] += h.get("sum", 0.0)
+        for lab in labels:
+            h = reg.histogram("serve_tokens_per_tick", kind=kind,
+                              **lab) or {}
+            out["count"] += h.get("count", 0)
+            out["sum"] += h.get("sum", 0.0)
     return out
 
 
-def serve_window(eng, arrivals, seconds: float, drain_s: float,
+def _recording_adoptions(cache, adopted: dict):
+    """Has the fleet's prefix cache note, by request id, how many prompt
+    tokens each adoption copied from it (the count ``adopt`` returns);
+    undone by deleting the instance attribute."""
+    real = cache.adopt
+
+    def adopt(engine, req):
+        n = real(engine, req)
+        adopted[req.rid] = n
+        return n
+    cache.adopt = adopt
+
+
+def serve_window(server, arrivals, seconds: float, drain_s: float,
                  tracer: Optional[Tracer] = None) -> dict:
     """Offer ``arrivals`` open loop for ``seconds``, then keep stepping
     up to ``drain_s`` more for the window's requests to finish.
 
     Returns the run's record: the flights due in the window (their
     submit, admission and token times in seconds after the window
-    opened), every tick (its host interval, the key count of each slot
-    it decoded and the prompt chunks it consumed), the registry's
-    tokens-per-tick counts over the window, the generator's lateness,
-    and the trace's events when ``tracer`` is given.
+    opened, and the prompt tokens a prefix cache supplied), every tick
+    (its host interval, the key count of each slot it decoded and the
+    prompt chunks it computed), the registry's tokens-per-tick counts
+    over the window, the generator's lateness, whether a prefix cache
+    served, and the trace's events when ``tracer`` is given.
     """
     from jax.profiler import TraceAnnotation
 
     from repro.serve.scheduler import SubmitError
-    flights = [Flight(a.due, a.prompt, a.max_new) for a in arrivals]
+    flights = [Flight(a.due, a.prompt, a.max_new, a.tenant)
+               for a in arrivals]
     live: List[Flight] = []
     ticks: List[dict] = []
+    adopted: dict = {}
+    cache = getattr(server, "prefix_cache", None)
     nxt = 0
     clock = time.perf_counter          # the engine's own stamps' clock
     t0 = clock()
-    c0 = _tick_counts(eng)
+    c0 = _tick_counts(server)
 
     def step() -> None:
         nonlocal live
         k = len(ticks)
         ts = clock() - t0
         with TraceAnnotation("engine_step", tick=k):
-            eng.step()
+            server.step()
         te = clock() - t0
         decode, chunks = [], []
         for f in live:
             p = f.req.prefill_progress
             if p > f.progress:
-                chunks.append((f.progress, p - f.progress))
+                skipped = adopted.pop(f.req.rid, 0)
+                f.adopted += skipped
+                start = f.progress + skipped
+                if p > start:
+                    chunks.append((start, p - start))
                 f.progress = p
             n = len(f.req.tokens)
             if n > len(f.t_tokens):
@@ -220,38 +296,46 @@ def serve_window(eng, arrivals, seconds: float, drain_s: float,
                       "chunks": chunks})
         live = [f for f in live if not f.req.finished]
 
+    if cache is not None:
+        _recording_adoptions(cache, adopted)
     tracing = False
-    while True:
-        now = clock() - t0
-        if now >= seconds:
-            break
-        if tracer is not None and not tracing \
-                and now >= seconds - tracer.seconds:
-            tracer.start()
-            tracing = True
-        while nxt < len(flights) and flights[nxt].due <= now:
-            f = flights[nxt]
-            nxt += 1
-            try:
-                with TraceAnnotation("submit"):
-                    f.req = eng.submit(f.prompt, max_new_tokens=f.max_new)
-            except SubmitError:
-                f.failed = True
-                continue
-            f.t_submit = clock() - t0
-            live.append(f)
-        if live:
+    try:
+        while True:
+            now = clock() - t0
+            if now >= seconds:
+                break
+            if tracer is not None and not tracing \
+                    and now >= seconds - tracer.seconds:
+                tracer.start()
+                tracing = True
+            while nxt < len(flights) and flights[nxt].due <= now:
+                f = flights[nxt]
+                nxt += 1
+                try:
+                    with TraceAnnotation("submit"):
+                        f.req = server.submit(f.prompt,
+                                              max_new_tokens=f.max_new,
+                                              tenant=f.tenant)
+                except SubmitError:
+                    f.failed = True
+                    continue
+                f.t_submit = clock() - t0
+                live.append(f)
+            if live:
+                step()
+            else:
+                until = min(flights[nxt].due if nxt < len(flights)
+                            else seconds, seconds)
+                with TraceAnnotation("wait_arrival"):
+                    time.sleep(max(0.0, until - (clock() - t0)))
+        c1 = _tick_counts(server)
+        if tracing:
+            tracer.stop()
+        while live and clock() - t0 < seconds + drain_s:
             step()
-        else:
-            until = min(flights[nxt].due if nxt < len(flights) else seconds,
-                        seconds)
-            with TraceAnnotation("wait_arrival"):
-                time.sleep(max(0.0, until - (clock() - t0)))
-    c1 = _tick_counts(eng)
-    if tracing:
-        tracer.stop()
-    while live and clock() - t0 < seconds + drain_s:
-        step()
+    finally:
+        if cache is not None:
+            del cache.adopt
     due = flights[:nxt]
     for f in due:
         if f.req is not None and f.req.t_admit is not None:
@@ -264,6 +348,7 @@ def serve_window(eng, arrivals, seconds: float, drain_s: float,
         "tokens_per_tick": {k: c1[k] - c0[k] for k in c1},
         "lateness_s": {"mean": sum(late) / len(late) if late else 0.0,
                        "max": max(late) if late else 0.0},
+        "prefix_cache": cache is not None,
         "trace": tracer.events if tracer is not None else None,
     }
 
@@ -315,22 +400,21 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     import jax
     cfg = cell.config
     m, n_slots = cfg["model"], cfg["spec"]["serve"]["n_slots"]
-    weights = check.make_weights(cfg, seed)
-    eng = build_engine(cfg, weights)
-    del weights
-    warm_up(eng)
+    server = build_server(cell, seed)
+    warm_up(server, cell.mix)
     arrivals = traffic.schedule(cell.mix, seed, seconds, n_slots,
                                 m["vocab_size"])
     setup_s = time.perf_counter() - t_start
 
     tracer = Tracer(min(TRACE_SECONDS, seconds / 2)) if trace else None
     try:
-        rec = serve_window(eng, arrivals, seconds, cell.mix["drain_s"],
+        rec = serve_window(server, arrivals, seconds, cell.mix["drain_s"],
                            tracer)
     finally:
         if tracer is not None:
             tracer.close()
     rec.update(setup_s=setup_s, model=m, engine=cfg["spec"]["serve"],
+               chips=len(engines(server)), counts=check.counts(cfg),
                peaks=peak_table)
     print(f"generator lateness: mean {rec['lateness_s']['mean'] * 1e3:.3f} "
           f"ms, max {rec['lateness_s']['max'] * 1e3:.3f} ms over "
@@ -351,7 +435,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
                                "idle_gaps": trace_reduce.idle_by_host(ev)}
 
     picked = check.sample(rec["flights"], seed, cell.mix["check_requests"])
-    del eng, rec
+    del server, rec
     gc.collect()
     weights = check.make_weights(cfg, seed)
     gap = check.served_gap(cfg, weights, picked)

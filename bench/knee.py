@@ -4,7 +4,8 @@
     python3 bench/knee.py --workload yi6b.prefill --seed 7 \\
         --rates 2,2.5,3,3.5 --seconds 30
 
-One process, one engine: for each rate the cell's traffic mix is
+One process, one server (one engine, or a cell's replicas behind the
+Router): for each rate the cell's traffic mix is
 offered open loop at that rate for ``--seconds``, then drained.  Each
 rate prints one JSON line: requests due and finished, the backlog (due
 but not yet admitted) sampled once a second, tokens per second, and the
@@ -32,7 +33,7 @@ def main() -> int:
     ap.add_argument("--seconds", type=float, default=30.0)
     args = ap.parse_args()
 
-    from bench import check, harness, traffic
+    from bench import harness, traffic
     from bench.run import enable_cache
     import jax
     enable_cache(jax)
@@ -41,18 +42,18 @@ def main() -> int:
         return 2
     cell = harness.load_cell(args.workload)
     cfg = cell.config
-    eng = harness.build_engine(cfg, check.make_weights(cfg, args.seed))
-    harness.warm_up(eng)
+    server = harness.build_server(cell, args.seed)
+    harness.warm_up(server, cell.mix)
     metric = {n: harness.reader(n) for n in
               ("ttft_p90_ms", "itl_p99_ms", "serve_tokens_per_s",
-               "queue_wait_p50_ms")}
+               "queue_wait_p50_ms", "prefix_hit_pct")}
     for i, rate in enumerate(float(r) for r in args.rates.split(",")):
         mix = dict(cell.mix, rate_rps=rate)
         arrivals = traffic.schedule(mix, args.seed + i, args.seconds,
                                     cfg["spec"]["serve"]["n_slots"],
                                     cfg["model"]["vocab_size"])
         t = time.perf_counter()
-        rec = harness.serve_window(eng, arrivals, args.seconds, 600.0)
+        rec = harness.serve_window(server, arrivals, args.seconds, 600.0)
         fl = rec["flights"]
         backlog = [sum(1 for f in fl if f.due <= s and
                        (f.t_admit is None or f.t_admit > s))

@@ -143,20 +143,19 @@ def measure(cell, seed: int, seconds: float, t_start: float,
             peak_table: dict, fixture_path=None) -> dict:
     cfg = cell.config
     m, n_slots = cfg["model"], cfg["spec"]["serve"]["n_slots"]
-    weights = check.make_weights(cfg, seed)
-    eng = harness.build_engine(cfg, weights)
-    del weights
-    harness.warm_up(eng)
+    server = harness.build_server(cell, seed)
+    harness.warm_up(server, cell.mix)
     arrivals = traffic.schedule(cell.mix, seed, seconds, n_slots,
                                 m["vocab_size"])
     setup_s = time.perf_counter() - t_start
     tracer = KeepingTracer(min(harness.TRACE_SECONDS, seconds / 2))
     try:
-        rec = harness.serve_window(eng, arrivals, seconds,
+        rec = harness.serve_window(server, arrivals, seconds,
                                    cell.mix["drain_s"], tracer)
     finally:
         tracer.close()
     rec.update(setup_s=setup_s, model=m, engine=cfg["spec"]["serve"],
+               chips=len(harness.engines(server)), counts=check.counts(cfg),
                peaks=peak_table)
     ev = rec["trace"]
     base = dict(rec, trace=engine_trace.strip(ev))

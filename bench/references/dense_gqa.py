@@ -28,6 +28,14 @@ F32 = jnp.float32
 FP8 = jnp.float8_e4m3fn
 FP8_MAX = 448.0
 
+# what this reference computes (``bench/check.py``'s ``reference``): the
+# sizes it reads, and the program attributes of the model kind it is
+FIELDS = ("d_model", "n_layers", "n_heads", "n_kv_heads", "head_dim",
+          "d_ff", "vocab_size", "rope_theta", "rope_fraction", "qkv_bias",
+          "norm_eps", "tie_embeddings")
+KIND = {"family": "dense", "block_pattern": ("attn",), "moe": None,
+        "norm_type": "rmsnorm", "mlp_type": "swiglu", "pos_type": "rope"}
+
 
 def shapes(m: dict) -> dict:
     d, h, kv, hd, ff, v, n = (m["d_model"], m["n_heads"], m["n_kv_heads"],

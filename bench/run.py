@@ -5,7 +5,8 @@
         --trace <0|1>
 
 Run from the checkout's root on a machine with the chips the cell asks
-for.  It builds the cell's engine from its configuration file with
+for.  It builds the cell's server from its configuration file (one
+engine, or one on each of the cell's chips behind the Router) with
 weights made from ``--seed``, warms up the programs the window runs,
 offers the cell's traffic for ``--seconds``, then checks a sample of
 the served tokens against the plain reference.  The last line of its

@@ -1,5 +1,6 @@
 """A cell at CPU-test size: the harness's code paths on a tiny model of
-each configuration's kind, with the real cells' limits."""
+each configuration's kind, with the real cells' chips and limits."""
+import copy
 import json
 
 import _paths
@@ -27,11 +28,47 @@ MIX = {"arrival": "poisson", "rate_rps": 6.0,
                          "min": 8, "max": 64},
        "due_at_start": "n_slots", "drain_s": 20, "check_requests": 8}
 
+# session traffic at test size: 4 tenants' 4-page system prompts
+SESSIONS = {"arrival": "poisson", "rate_rps": 8.0,
+            "sessions": {"tenants": 4, "system_prompt_tokens": 64,
+                         "tenant_zipf": 1.0},
+            "prompt_tokens": {"dist": "lognormal", "median": 32,
+                              "sigma": 0.5, "min": 16, "max": 64},
+            "output_tokens": {"dist": "lognormal", "median": 32,
+                              "sigma": 0.7, "min": 8, "max": 64},
+            "due_at_start": 0, "drain_s": 20, "check_requests": 8}
+
+
+# A fleet cell as a manifest entry would describe it: four one-chip
+# replicas behind the Router under session traffic, reporting what such
+# a cell would (no fleet cell is in BENCHMARK.json yet)
+FLEET = "yi6b.fleet4.sessions"
+FLEET_E2E = [dict(m, workloads=[FLEET]) for m in MANIFEST["end_to_end"]
+             if m["name"] in ("serve_tokens_per_s", "itl_p99_ms",
+                              "setup_s")]
+FLEET_PER_LAYER = [
+    {"name": f"{n}.fleet", "unit": u, "better": b, "source": src,
+     "layer": layer, "moves": "serve_tokens_per_s", "workloads": [FLEET]}
+    for n, u, b, src, layer in [
+        ("prefix_hit_pct", "%", "higher", "program_counter", "router"),
+        ("step_mfu_pct", "%", "higher", "host_clock", "steps"),
+        ("device_idle_pct", "%", "lower", "device_trace", "device"),
+        ("window_compiles", "compiles", "lower", "program_counter",
+         "steps")]]
+
 
 def cell(name: str, arch: str, mix=None) -> harness.Cell:
-    """Cell ``name`` of the manifest, its configuration swapped for the
-    -smoke model ``arch`` and its traffic for ``mix``."""
-    real = harness.load_cell(name, MANIFEST)
+    """Cell ``name`` of the manifest (or the fleet cell above), its
+    configuration swapped for the -smoke model ``arch`` and its traffic
+    for a copy of ``mix`` (by default the test-size mix of the cell's
+    kind of traffic)."""
+    if name == FLEET:
+        real = harness.Cell(name, 4, {}, SESSIONS, {"logit_gap": 0.9},
+                            FLEET_E2E, FLEET_PER_LAYER)
+    else:
+        real = harness.load_cell(name, MANIFEST)
+    if mix is None:
+        mix = SESSIONS if "sessions" in real.mix else MIX
     config = {
         "model": SIZES[arch], "reference": "dense_gqa",
         "spec": {"kind": "serve", "arch": arch, "name": "smoke",
@@ -39,5 +76,5 @@ def cell(name: str, arch: str, mix=None) -> harness.Cell:
                            "max_seq_len": 256, "max_prompt_len": 256,
                            "prefill_chunk": 64, "max_new": 32,
                            "n_requests": 1}}}
-    return harness.Cell(name, 1, config, dict(mix or MIX), real.limits,
-                        real.end_to_end, real.per_layer)
+    return harness.Cell(name, real.chips, config, copy.deepcopy(mix),
+                        real.limits, real.end_to_end, real.per_layer)

@@ -1,17 +1,10 @@
-"""Each test's changes to the shared CPU-test traffic mix stay its own.
+"""Eight host CPU devices, so that a fleet cell's replicas each get one
+of their own: set before JAX first starts its backend (the same flag
+and count as ``tests/conftest.py``, whichever of the two loads first)."""
+import os
 
-``_smoke.cell`` hands out a shallow copy of ``_smoke.MIX``, so a test
-that edits one of its length distributions in place edits the mix of
-every later test in the same process."""
-import copy
-
-import _smoke
-import pytest
-
-
-@pytest.fixture(autouse=True)
-def _fresh_mix():
-    saved = copy.deepcopy(_smoke.MIX)
-    yield
-    _smoke.MIX.clear()
-    _smoke.MIX.update(saved)
+if "xla_force_host_platform_device_count" not in os.environ.get(
+        "XLA_FLAGS", ""):
+    os.environ["XLA_FLAGS"] = (
+        os.environ.get("XLA_FLAGS", "")
+        + " --xla_force_host_platform_device_count=8").strip()

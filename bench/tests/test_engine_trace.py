@@ -15,7 +15,7 @@ import _smoke
 import pytest
 
 from bench import engine_trace as et
-from bench import harness, phases, trace_reduce
+from bench import check, harness, phases, trace_reduce
 from repro.obs import ENGINE_PHASES
 
 DATA = _paths.ROOT / "bench" / "tests" / "data"
@@ -257,6 +257,7 @@ def test_existing_readers_read_the_same_beside_engine_events(recorded):
                      .read_text())
     rec = {"trace": ev, "ticks": recorded["ticks"], "model": cfg["model"],
            "engine": cfg["spec"]["serve"], "peaks": _smoke.PEAKS,
+           "counts": check.counts(cfg), "chips": 1, "prefix_cache": False,
            "flights": [], "window_s": trace_reduce.window_s(ev),
            "tokens_per_tick": {"count": 0.0, "sum": 0.0}, "setup_s": 0.0}
     bare = dict(rec, trace=et.strip(ev))

@@ -32,6 +32,20 @@ def test_cell_files_exist(cell):
     assert w["chips"] in (1, 4)
 
 
+def test_workload_entries_carry_only_the_manifest_keys():
+    """A cell on N chips is N one-chip replicas behind the Router: the
+    number is its ``chips``; a workload entry has no key of its own for
+    it, since the manifest's format allows none."""
+    for w in MANIFEST["workloads"]:
+        assert set(w) == {"name", "config", "traffic", "chips", "why"}, w
+        assert 1 <= len(w["why"]) <= 200 and "\n" not in w["why"]
+    for cell in CELLS:
+        c = harness.load_cell(cell, MANIFEST)
+        assert c.chips in (1, 4)
+    assert sum(w["chips"] == 4 for w in MANIFEST["workloads"]) <= max(
+        1, len(CELLS) // 2)
+
+
 def test_every_config_has_a_cell():
     used = {w["config"] for w in MANIFEST["workloads"]}
     assert used == {c["name"] for c in MANIFEST["configs"]}

@@ -46,8 +46,22 @@ def state_unchanged(monkeypatch):
                         frozen(steps.build_mixed_step))
 
 
+@pytest.fixture
+def prefix_not_copied(monkeypatch):
+    """An adoption from the fleet's prefix cache skips the cached prompt
+    tokens without copying their pages into the slot."""
+    from repro.serve.fleet import PrefixCache
+
+    def adopt(self, engine, req):
+        k, _ = (0, None) if req.prefill_progress else self.lookup(req.prompt)
+        req.prefill_progress = req.prefill_progress or k * self.page_size
+        return k * self.page_size
+    monkeypatch.setattr(PrefixCache, "adopt", adopt)
+
+
 CASES = [("yi6b.prefill", "yi-6b-smoke"),
-         ("chatglm3.decode", "chatglm3-6b-smoke")]
+         ("chatglm3.decode", "chatglm3-6b-smoke"),
+         ("yi6b.fleet4.sessions", "yi-6b-smoke")]
 
 
 @pytest.mark.parametrize("name, arch", CASES)
@@ -74,6 +88,63 @@ def test_unchanged_state_is_not_correct(name, arch, state_unchanged):
         r["check"]["logit_gap"]["limit"]
 
 
+def test_prefix_not_copied_is_not_correct(prefix_not_copied):
+    r = run("yi6b.fleet4.sessions", "yi-6b-smoke")
+    assert r["correct"] is False
+    assert r["check"]["logit_gap"]["value"] > \
+        r["check"]["logit_gap"]["limit"]
+
+
+def _medium(monkeypatch, name):
+    """Cell ``name`` on a model the CPU can hold that is large enough
+    for its rounding to look like the chip's: d_model 256, 4 layers, 8
+    heads over 2 KV heads, vocab 4096; slots of 512 tokens."""
+    import dataclasses
+
+    from repro.configs import registry
+    small = dict(_smoke.SIZES["yi-6b-smoke"], d_model=256, n_layers=4,
+                 n_heads=8, n_kv_heads=2, head_dim=32, d_ff=688,
+                 vocab_size=4096)
+    cfg = dataclasses.replace(registry.get("yi-6b-smoke"),
+                              **{k: small[k] for k in (
+                                  "d_model", "n_layers", "n_heads",
+                                  "n_kv_heads", "d_ff", "vocab_size")})
+    real_get = registry.get
+    monkeypatch.setattr(registry, "get", lambda i: cfg
+                        if i == "yi-6b-smoke" else real_get(i))
+    monkeypatch.setitem(_smoke.SIZES, "yi-6b-smoke", small)
+    c = _smoke.cell(name, "yi-6b-smoke")
+    c.config["spec"]["serve"].update(max_seq_len=512, max_prompt_len=512,
+                                     prefill_chunk=128)
+    return c, small
+
+
+def _program_and_control(c, seed, vocab):
+    server = harness.build_server(c, seed)
+    harness.warm_up(server, c.mix)
+    arrivals = traffic.schedule(c.mix, seed, 2.0, 4, vocab)
+    rec = harness.serve_window(server, arrivals, 2.0, c.mix["drain_s"])
+    picked = check.sample(rec["flights"], seed, 4)
+    del server
+    weights = check.make_weights(c.config, seed)
+    return (picked, check.served_gap(c.config, weights, picked),
+            check.control_gaps(c.config, weights, picked))
+
+
+def test_fleet_control_reads_far_above_the_program(monkeypatch):
+    """The fleet's control at the same size: four replicas serving
+    sessions whose 256-token system prompts come from the prefix cache;
+    not yet measured on the chip."""
+    c, small = _medium(monkeypatch, "yi6b.fleet4.sessions")
+    c.mix["sessions"].update(system_prompt_tokens=256)
+    for seed in (SEED, SEED + 1):
+        picked, program, control = _program_and_control(
+            c, seed, small["vocab_size"])
+        assert any(f.adopted for f in picked)
+        assert sum(len(f.tokens) for f in picked) >= 30
+        assert control > 3 * max(program, 0.01), (program, control)
+
+
 def test_control_reads_far_above_the_program(monkeypatch):
     """The control (the reference in fp8) at a size the CPU can hold:
     d_model 256, 4 layers, 8 heads over 2 KV heads, vocab 4096.  On the
@@ -94,21 +165,11 @@ def test_control_reads_far_above_the_program(monkeypatch):
     monkeypatch.setattr(registry, "get", lambda i: cfg
                         if i == "yi-6b-smoke" else real_get(i))
     monkeypatch.setitem(_smoke.SIZES, "yi-6b-smoke", small)
-    c = _smoke.cell("yi6b.prefill", "yi-6b-smoke")
-    c.config["spec"]["serve"].update(max_seq_len=512, max_prompt_len=512,
-                                     prefill_chunk=128)
+    c, small = _medium(monkeypatch, "yi6b.prefill")
     c.mix["prompt_tokens"].update(median=300, max=400)
     c.mix["output_tokens"].update(median=24, max=64)
     for seed in (SEED, SEED + 1):
-        weights = check.make_weights(c.config, seed)
-        eng = harness.build_engine(c.config, weights)
-        harness.warm_up(eng)
-        arrivals = traffic.schedule(c.mix, seed, 2.0, 4,
-                                    small["vocab_size"])
-        rec = harness.serve_window(eng, arrivals, 2.0, c.mix["drain_s"])
-        picked = check.sample(rec["flights"], seed, 4)
-        del eng
-        program = check.served_gap(c.config, weights, picked)
-        control = check.control_gaps(c.config, weights, picked)
+        picked, program, control = _program_and_control(
+            c, seed, small["vocab_size"])
         assert sum(len(f.tokens) for f in picked) >= 50
         assert control > 3 * max(program, 0.01), (program, control)

@@ -5,7 +5,7 @@ import time
 import _smoke
 import pytest
 
-from bench import check, harness, traffic
+from bench import harness, traffic
 
 SEED = 2**31 + 77          # larger than 32 signed bits hold
 
@@ -13,8 +13,8 @@ SEED = 2**31 + 77          # larger than 32 signed bits hold
 @pytest.fixture(scope="module")
 def engine():
     c = _smoke.cell("yi6b.prefill", "yi-6b-smoke")
-    eng = harness.build_engine(c.config, check.make_weights(c.config, SEED))
-    harness.warm_up(eng)
+    eng = harness.build_server(c, SEED)
+    harness.warm_up(eng, c.mix)
     return c, eng
 
 

@@ -23,7 +23,15 @@ A mix file holds:
 * ``drain_s`` - how long the harness keeps stepping after the window
   for the window's requests to finish;
 * ``check_requests`` - how many finished requests the output check
-  compares with the reference (the longest among them).
+  compares with the reference (the longest among them);
+* ``sessions`` (optional) - ``{"tenants", "system_prompt_tokens",
+  "tenant_zipf"}``: each tenant has one system prompt, its token ids
+  drawn from the seed, and each request's prompt is its tenant's system
+  prompt followed by a turn of ``prompt_tokens``.  The tenant of each
+  arrival follows a Zipf law over the tenants (exponent
+  ``tenant_zipf``), as mid-quantiles in the one fixed order like the
+  sizes and gaps, so two seeds send the same tenants at the same times.
+  A mix without it draws exactly what it drew before sessions existed.
 """
 from __future__ import annotations
 
@@ -47,6 +55,7 @@ class Arrival:
     due: float                 # seconds after the window opens
     prompt: List[int]
     max_new: int
+    tenant: str = "default"
 
 
 def load_mix(name: str) -> dict:
@@ -65,6 +74,15 @@ def quantiles(spec: dict, n: int) -> np.ndarray:
     z = np.array([NormalDist().inv_cdf((i + 0.5) / n) for i in range(n)])
     x = np.exp(np.log(spec["median"]) + spec["sigma"] * z)
     return np.clip(np.rint(x), spec["min"], spec["max"]).astype(int)
+
+
+def zipf_quantiles(n_tenants: int, exponent: float, n: int) -> np.ndarray:
+    """The ``n`` mid-quantiles of a Zipf law over ``n_tenants`` tenants
+    (tenant i weighs ``(i + 1) ** -exponent``): tenant indices."""
+    w = np.arange(1, n_tenants + 1, dtype=float) ** -exponent
+    cdf = np.cumsum(w) / w.sum()
+    u = (np.arange(n) + 0.5) / n
+    return np.minimum(np.searchsorted(cdf, u, side="right"), n_tenants - 1)
 
 
 def n_due_at_start(mix: dict, n_slots: int) -> int:
@@ -87,7 +105,18 @@ def schedule(mix: dict, seed: int, seconds: float, n_slots: int,
     gaps = order.permutation(-np.log1p(-u) / mix["rate_rps"])
     rng = rng_for(seed)
     due = np.concatenate([np.zeros(n0), np.cumsum(gaps)])
+    sessions = mix.get("sessions")
+    if sessions is None:
+        return [Arrival(float(due[i]),
+                        rng.integers(0, vocab, int(plen[i])).tolist(),
+                        int(olen[i]))
+                for i in range(n)]
+    tenant = order.permutation(zipf_quantiles(
+        sessions["tenants"], sessions["tenant_zipf"], n))
+    system = [rng.integers(0, vocab, sessions["system_prompt_tokens"])
+              .tolist() for _ in range(sessions["tenants"])]
     return [Arrival(float(due[i]),
-                    rng.integers(0, vocab, int(plen[i])).tolist(),
-                    int(olen[i]))
+                    system[tenant[i]]
+                    + rng.integers(0, vocab, int(plen[i])).tolist(),
+                    int(olen[i]), f"tenant{tenant[i]}")
             for i in range(n)]
